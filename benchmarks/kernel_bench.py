@@ -27,7 +27,7 @@ from repro.kernels import (
     ssd_ref_chunked,
 )
 
-__all__ = ["run", "union_cases", "format_table"]
+__all__ = ["run", "format_table"]
 
 
 def _time(fn, *args, reps=3, **kw):
@@ -72,17 +72,18 @@ def run() -> List[Dict]:
                  "interp_s": t_k, "ref_s": t_r, "max_err": err,
                  "tile_flops": 2 * 128 * 128 * N + 2 * 128 * 128 * 4 * P})
 
-    # fragment gather
-    Ns, C, R = 4096, 512, 2048
-    src = jax.random.normal(key, (Ns, C), jnp.float32)
-    idx = np.concatenate([np.arange(1024, 1024 + 1024), np.arange(0, 1024)])
-    t_k, out_k = _time(fragment_gather, src, idx, row_block=8, col_block=512, interpret=True)
-    t_r, out_r = _time(gather_ref, src, jnp.asarray(idx))
+    # fragment gather: the device tier's UNION shape — tile-aligned row runs
+    # of one pinned 1-D column, concatenated in serving order
+    Ns = 65536
+    src = jax.random.normal(key, (Ns,), jnp.float32)
+    bounds = [(0, 8192), (16384, 24576), (40960, 49152)]
+    t_k, out_k = _time(fragment_gather, src, bounds, interpret=True)
+    t_r, out_r = _time(gather_ref, src, bounds)
     err = float(jnp.max(jnp.abs(out_k - out_r)))
-    rows.append({"kernel": "fragment_gather", "shape": f"{R}x{C} of {Ns}x{C}",
+    rows.append({"kernel": "fragment_gather",
+                 "shape": f"{len(bounds)} runs, {out_k.shape[0]} of {Ns} f32",
                  "interp_s": t_k, "ref_s": t_r, "max_err": err,
                  "tile_flops": 0})
-    rows.extend(union_cases(key))
 
     # dequant
     R2, C2 = 2048, 1024
@@ -97,72 +98,14 @@ def run() -> List[Dict]:
     return rows
 
 
-def union_cases(key) -> List[Dict]:
-    """``fragment_gather`` in the exact shape the device cache tier calls it:
-    the hit∪residual UNION — several contiguous row runs of one pinned
-    element, concatenated into the serving order.
-
-    Correctness is MEASURED (interpret mode, must be bit-exact vs the jnp
-    take reference).  Throughput is MODELED against TPU hardware walls: the
-    kernel's UNION moves ``2 × bytes`` of HBM traffic (read + write, at
-    ``hbm_bw``) while the numpy reference path assembles on host and pushes
-    every consumed byte over the host link (``host_bw``) — interpret-mode
-    wall time on a CPU container says nothing about either, so the modeled
-    numbers are what ``--check`` gates on.  The fast-path case must win by
-    construction (HBM is ~25× the host link); the fallback case documents
-    the RB=1 downgrade cost instead of hiding it.
-    """
-    from repro.launch.roofline import HW_V5E
-
-    rows: List[Dict] = []
-    cases = [
-        # (name, run bounds, row_block) — block-run UNION of a 64k-row pin
-        ("union_fast", [(0, 8192), (16384, 24576), (40960, 49152)], 1024),
-        # runs shifted off alignment: silent-downgrade shape (small RB) —
-        # smaller runs, because interpret mode replays the grid per block
-        ("union_fallback", [(3, 2051), (16387, 18435), (40963, 43011)], 8),
-    ]
-    Ns, C = 65536, 8
-    src = jax.random.normal(key, (Ns, C), jnp.float32)
-    for name, bounds, rb in cases:
-        idx = np.concatenate(
-            [np.arange(lo, hi, dtype=np.int32) for lo, hi in bounds]
-        )
-        R = int(idx.shape[0])
-        t_k, out_k = _time(
-            fragment_gather, src, idx, row_block=rb, col_block=C, interpret=True
-        )
-        t_r, out_r = _time(gather_ref, src, jnp.asarray(idx))
-        err = float(jnp.max(jnp.abs(out_k - out_r)))
-        nbytes = R * C * 4
-        kernel_s = 2.0 * nbytes / HW_V5E["hbm_bw"]
-        ref_s = nbytes / HW_V5E["host_bw"]
-        rows.append({
-            "kernel": f"fragment_gather/{name}",
-            "shape": f"{len(bounds)} runs, {R}x{C} of {Ns}x{C}",
-            "interp_s": t_k, "ref_s": t_r, "max_err": err, "tile_flops": 0,
-            "union_bytes": nbytes,
-            "modeled_kernel_gbps": nbytes / kernel_s / 1e9,
-            "modeled_ref_gbps": nbytes / ref_s / 1e9,
-            "fast_path": rb > 1,
-        })
-    return rows
-
-
 def format_table(rows: List[Dict]) -> str:
     out = [
-        "| Kernel | Shape | interpret (s) | pure-jnp ref (s) | max err | modeled TPU kernel (GB/s) | modeled host ref (GB/s) |",
-        "|---|---|---|---|---|---|---|",
+        "| Kernel | Shape | interpret (s) | pure-jnp ref (s) | max err |",
+        "|---|---|---|---|---|",
     ]
     for r in rows:
-        mk = r.get("modeled_kernel_gbps")
-        mr = r.get("modeled_ref_gbps")
         out.append(
-            "| {kernel} | {shape} | {interp_s:.3f} | {ref_s:.3f} | {max_err:.2e} | {mk} | {mr} |".format(
-                mk=f"{mk:.0f}" if mk is not None else "—",
-                mr=f"{mr:.0f}" if mr is not None else "—",
-                **r,
-            )
+            "| {kernel} | {shape} | {interp_s:.3f} | {ref_s:.3f} | {max_err:.2e} |".format(**r)
         )
     return "\n".join(out)
 

@@ -143,6 +143,7 @@ def pipeline_rows() -> List[Dict]:
     ``--xla_force_host_platform_device_count`` (must precede jax init)."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # the virtual mesh; never claim the chip
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={PIPELINE_STAGES}"

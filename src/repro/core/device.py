@@ -7,14 +7,14 @@ already "had".  :class:`DeviceTier` closes that gap:
 
 - **pinning**: a cache element's payload columns are uploaded once as jax
   device arrays (column-major — one 1-D array per ``(element, column)``,
-  padded to :data:`ROW_BLOCK` rows so every fragment boundary the gather
-  kernel sees is tile-addressable).  Pins are keyed by ``elem_id``; element
-  ids are never reused (merges mint new elements), so a stale pin can never
-  alias a different payload.
+  padded to :data:`ROW_BLOCK` rows, one ``(8, 128)`` tile of the column's
+  lane-dense view).  Pins are keyed by ``elem_id``; element ids are never
+  reused (merges mint new elements), so a stale pin can never alias a
+  different payload.
 - **serving**: :func:`device_union` assembles hit∪residual output columns
   *on device* — contiguous row runs of pinned elements go through the
-  ``fragment_gather`` Pallas kernel (RB-aligned block runs take its tiled
-  fast path; non-aligned runs are counted as fallback downgrades), and the
+  ``fragment_gather`` Pallas kernel when they start and end on whole tiles
+  (other runs are XLA slices, counted as ``gather_fallbacks``), and the
   per-source outputs are concatenated device-side.  No host round-trip.
 - **merge replication**: when the store merges two pinned elements, the
   merged element's device columns are built by gathering from the parents'
@@ -55,18 +55,10 @@ __all__ = [
     "device_union",
 ]
 
-# pin-time padding granularity: every pinned column is padded to a multiple
-# of ROW_BLOCK rows so the gather kernel's smallest tile is always in-bounds
-ROW_BLOCK = 8
-
-# candidate row-block sizes for a union gather, largest first — bigger
-# blocks mean fewer grid steps (and on TPU, fewer/larger DMAs)
-_RB_CANDIDATES = (4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8)
-
-# non-aligned gathers above this row count skip the RB=1 kernel (row-granular
-# grid steps are pure overhead in interpret mode) for an XLA take — still a
-# device-side gather, still counted as a fallback downgrade
-FALLBACK_KERNEL_MAX_ROWS = 1024
+# pin-time padding granularity: every pinned column is padded to whole
+# (8, 128) tiles of its lane-dense view, so any tile-aligned run of a pin is
+# a run of whole fragment_gather blocks (= fragment_gather's TILE_ROWS)
+ROW_BLOCK = 1024
 
 
 def _bump(ledger: Optional[Dict[str, int]], key: str, by: int = 1) -> None:
@@ -361,41 +353,6 @@ class DeviceTier:
 # device-side UNION assembly
 # ---------------------------------------------------------------------------
 
-def _choose_row_block(bounds: Sequence[Tuple[int, int]]) -> Optional[int]:
-    """Largest candidate RB for which every run is block-aligned (start and
-    length both multiples of RB) — the kernel's tiled fast path; None when
-    no candidate fits (the RB=1 / XLA-take fallback)."""
-    for rb in _RB_CANDIDATES:
-        if all(lo % rb == 0 and (hi - lo) % rb == 0 for lo, hi in bounds):
-            return rb
-    return None
-
-
-def _gather_runs(src1d, bounds, interpret, ledger):
-    """Extract and concatenate ``bounds`` row runs of one padded source
-    column via ``fragment_gather``.  Aligned runs take the block-run fast
-    path; others are counted as fallback downgrades."""
-    import jax.numpy as jnp
-
-    from repro.kernels.fragment_gather.ops import fragment_gather
-
-    idx = np.concatenate(
-        [np.arange(lo, hi, dtype=np.int32) for lo, hi in bounds]
-    )
-    rb = _choose_row_block(bounds)
-    if rb is not None:
-        _bump(ledger, "gather_fast")
-        return fragment_gather(
-            src1d.reshape(-1, 1), idx, row_block=rb, interpret=interpret
-        )[:, 0]
-    _bump(ledger, "gather_fallbacks")
-    if idx.shape[0] <= FALLBACK_KERNEL_MAX_ROWS:
-        return fragment_gather(
-            src1d.reshape(-1, 1), idx, row_block=ROW_BLOCK, interpret=interpret
-        )[:, 0]
-    return jnp.take(src1d, jnp.asarray(idx), axis=0)
-
-
 def device_union(
     runs: Sequence[Tuple[Mapping[str, Any], int, int]],
     columns: Sequence[str],
@@ -408,13 +365,17 @@ def device_union(
     ``runs`` is the output's row layout **in final row order**: each entry is
     ``(arrays, lo, hi)`` — a provider mapping of padded 1-D device columns
     and the half-open real-row range it contributes.  Consecutive runs from
-    the same provider become ONE ``fragment_gather`` call (the multi-interval
-    hit case — a true block-run gather); single-run groups are device slices
-    (a gather would be the identity).  Returns exact-length device columns,
-    bitwise-equal to the numpy reference ``np.concatenate`` of the same
-    slices followed by ``jnp.asarray``.
+    the same provider become ONE ``fragment_gather`` call when every run
+    starts and ends on a whole tile (the multi-interval hit case — a true
+    block-run gather, counted as ``gather_fast``); other multi-run groups are
+    XLA slices, counted as ``gather_fallbacks``, and single-run groups are
+    plain slices (a gather would be the identity).  Returns exact-length
+    device columns, bitwise-equal to the numpy reference ``np.concatenate``
+    of the same slices followed by ``jnp.asarray``.
     """
     import jax.numpy as jnp
+
+    from repro.kernels.fragment_gather.ops import fragment_gather, tile_aligned
 
     if not runs:
         return {}
@@ -437,11 +398,13 @@ def device_union(
         parts = []
         for arrays, bounds in groups:
             src = arrays[c]
-            if len(bounds) == 1:
-                lo, hi = bounds[0]
-                parts.append(src[lo:hi])
+            if len(bounds) > 1 and tile_aligned(int(src.shape[0]), bounds):
+                _bump(ledger, "gather_fast")
+                parts.append(fragment_gather(src, bounds, interpret=interpret))
             else:
-                parts.append(_gather_runs(src, bounds, interpret, ledger))
+                if len(bounds) > 1:
+                    _bump(ledger, "gather_fallbacks")
+                parts.extend(src[lo:hi] for lo, hi in bounds)
         col = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         out[c] = col
         total_rows = int(col.shape[0])

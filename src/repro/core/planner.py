@@ -66,8 +66,8 @@ class ScanReport:
     # device-tier ledger (all zero on the numpy path)
     bytes_h2d: int = 0  # host->device bytes this scan uploaded
     device_hits: int = 0  # hit columns served from resident device pins
-    gather_fast: int = 0  # fragment_gather block-run fast-path calls
-    gather_fallbacks: int = 0  # non-RB-aligned gathers (RB=1 / XLA take)
+    gather_fast: int = 0  # multi-run gathers served by fragment_gather
+    gather_fallbacks: int = 0  # multi-run gathers off the tile grid (XLA slices)
     device_union_bytes: int = 0  # output bytes assembled on device
 
     @property

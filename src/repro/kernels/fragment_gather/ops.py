@@ -1,130 +1,94 @@
-"""jit'd wrapper: arbitrary row-index gather via the tiled Pallas kernel.
+"""jit'd wrapper: concatenate tile-aligned row runs of a pinned 1-D column.
 
-Converts a per-row index vector into the kernel's block-run form:
-if every RB-aligned group of indices is a contiguous run starting at an
-RB-aligned source row (the common case — fragments are contiguous row
-ranges), rows move in (RB, CB) tiles; otherwise falls back to RB=1
-(row-granular DMA, still lane-tiled in columns).  Fallback downgrades are
-counted in :data:`GATHER_STATS` so bench regressions are diagnosable
-(silent RB=1 gathers used to be indistinguishable from the fast path).
+The caller passes the output's row layout as ``(lo, hi)`` runs of ``src``.
+Runs that start and end on a :data:`TILE_ROWS` boundary go through the
+Pallas kernel in blocks of the largest power of two, up to
+:data:`MAX_ROW_BLOCK` rows, that divides every run start and length.  Other
+runs are the caller's to serve with plain XLA slices (``core.device``
+counts them as ``gather_fallbacks``): the TPU compiler refuses blocks
+narrower than one ``(8, 128)`` tile, so the kernel has no row-granular
+path.
 
-The Pallas call itself is wrapped in a memoized ``jax.jit``: eager
-interpret mode replays the grid in Python (milliseconds per step), while
-the jitted interpreter runs it as one XLA loop — mandatory for using the
-kernel on the differential-cache serving path.
+The Pallas call is wrapped in a memoized ``jax.jit``: eager interpret mode
+replays the grid in Python (milliseconds per step), while the jitted
+interpreter runs it as one XLA loop.
 """
 
 from __future__ import annotations
 
 import functools
-import threading
-from typing import Optional
+import math
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.fragment_gather.kernel import fragment_gather_call
+from repro.kernels.fragment_gather.kernel import TILE_ROWS, fragment_gather_call
 
-__all__ = ["fragment_gather", "GATHER_STATS", "GatherStats"]
+__all__ = ["fragment_gather", "tile_aligned", "block_plan", "resolve_interpret"]
 
-
-class GatherStats:
-    """Process-wide gather path counters (thread-safe increments)."""
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self.calls = 0
-        self.fast_path = 0
-        self.fallbacks = 0  # RB=1 downgrades (non-block-aligned indices)
-
-    def count(self, fast: bool) -> None:
-        with self._lock:
-            self.calls += 1
-            if fast:
-                self.fast_path += 1
-            else:
-                self.fallbacks += 1
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return {
-                "calls": self.calls,
-                "fast_path": self.fast_path,
-                "fallbacks": self.fallbacks,
-            }
+# largest block: 256 KiB of f32 per buffer, so the input and output blocks,
+# double-buffered, take 1 MiB of VMEM
+MAX_ROW_BLOCK = 64 * TILE_ROWS
 
 
-GATHER_STATS = GatherStats()
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``None`` means: compiled on a TPU, the Pallas interpreter elsewhere."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def tile_aligned(n_rows: int, bounds: Sequence[Tuple[int, int]]) -> bool:
+    """Whether the kernel can serve ``bounds`` of an ``n_rows`` column:
+    the column and every run start and end on whole tiles."""
+    return n_rows % TILE_ROWS == 0 and all(
+        lo % TILE_ROWS == 0 and hi % TILE_ROWS == 0 for lo, hi in bounds
+    )
 
 
-def _pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
-    n = x.shape[axis]
-    pad = (-n) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+def block_plan(bounds: Sequence[Tuple[int, int]]) -> Tuple[int, np.ndarray]:
+    """The row block for tile-aligned ``bounds`` (the largest power of two
+    dividing every run start and length, at most :data:`MAX_ROW_BLOCK`)
+    and the source block index of each output block."""
+    g = 0
+    for lo, hi in bounds:
+        g = math.gcd(g, lo, hi - lo)
+    rb = min(g & -g, MAX_ROW_BLOCK)
+    block_idx = np.concatenate(
+        [np.arange(lo // rb, hi // rb, dtype=np.int32) for lo, hi in bounds]
+    )
+    return rb, block_idx
 
 
-@functools.lru_cache(maxsize=256)
-def _compiled_call(row_block: int, col_block: int, out_rows: int, interpret: bool):
+@functools.lru_cache(maxsize=64)
+def _compiled_call(row_block: int, interpret: bool):
     return jax.jit(
         functools.partial(
-            fragment_gather_call,
-            row_block=row_block,
-            col_block=col_block,
-            out_rows=out_rows,
-            interpret=interpret,
+            fragment_gather_call, row_block=row_block, interpret=interpret
         )
     )
 
 
 def fragment_gather(
-    src: jax.Array,  # (Ns, C)
-    row_idx,  # (R,) int — host-known fragment layout (numpy or list)
+    src: jax.Array,  # (n,) device column, n a multiple of TILE_ROWS
+    bounds: Sequence[Tuple[int, int]],  # half-open row runs, output order
     *,
-    row_block: int = 8,
-    col_block: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    interpret = _auto_interpret() if interpret is None else interpret
-    row_idx = np.asarray(row_idx, np.int32)
-    R = int(row_idx.shape[0])
-    Ns, C = src.shape
-    if R == 0:
+    """``concat(src[lo:hi] for lo, hi in bounds)`` on device."""
+    interpret = resolve_interpret(interpret)
+    bounds = [(int(lo), int(hi)) for lo, hi in bounds if hi > lo]
+    if not bounds:
         return src[:0]
-    # every index must address a REAL source row: the wrapper pads src up to
-    # the tile multiple below, and an index into that padded tail would
-    # silently gather zeros into the UNION output
-    lo_i, hi_i = int(row_idx.min()), int(row_idx.max())
-    if lo_i < 0 or hi_i >= Ns:
-        raise IndexError(
-            f"row_idx out of range: [{lo_i}, {hi_i}] vs {Ns} source rows "
-            f"(indices into the tile-padded tail would leak zero rows)"
+    n = int(src.shape[0])
+    lo_min = min(lo for lo, _ in bounds)
+    hi_max = max(hi for _, hi in bounds)
+    if lo_min < 0 or hi_max > n:
+        raise IndexError(f"runs span [{lo_min}, {hi_max}) of a {n}-row column")
+    if src.ndim != 1 or not tile_aligned(n, bounds):
+        raise ValueError(
+            f"fragment_gather takes runs of a 1-D column on {TILE_ROWS}-row "
+            f"tiles; got shape {tuple(src.shape)}, runs {bounds[:4]}..."
         )
-
-    # try RB-tiled: indices in each RB group contiguous AND tile-aligned
-    rb = row_block
-    ok = R % rb == 0
-    if ok:
-        grouped = row_idx.reshape(-1, rb)
-        runs = (grouped == grouped[:, :1] + np.arange(rb, dtype=np.int32)).all()
-        aligned = (grouped[:, 0] % rb == 0).all()
-        ok = bool(runs and aligned)
-    if not ok:
-        rb = 1
-    GATHER_STATS.count(fast=rb > 1)
-
-    block_idx = jnp.asarray(row_idx.reshape(-1, rb)[:, 0] // rb, jnp.int32)
-    out_rows = R if R % rb == 0 else R  # R % 1 == 0 always in fallback
-
-    cb = min(col_block, C) if C >= 128 else C
-    src_p = _pad_axis(_pad_axis(src, 0, rb), 1, cb)
-    out = _compiled_call(rb, cb, out_rows, interpret)(src_p, block_idx)
-    return out[:R, :C]
+    rb, block_idx = block_plan(bounds)
+    return _compiled_call(rb, interpret)(src, jnp.asarray(block_idx))

@@ -1,6 +1,8 @@
-"""Oracle: plain jnp row gather."""
+"""Oracle: plain jnp slices and one concatenate."""
 
 from __future__ import annotations
+
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +10,6 @@ import jax.numpy as jnp
 __all__ = ["gather_ref"]
 
 
-def gather_ref(src: jax.Array, row_idx: jax.Array) -> jax.Array:
-    """out[i] = src[row_idx[i]] — (R,) indices over (Ns, C) rows."""
-    return jnp.take(src, row_idx, axis=0)
+def gather_ref(src: jax.Array, bounds: Sequence[Tuple[int, int]]) -> jax.Array:
+    """``concat(src[lo:hi] for lo, hi in bounds)`` of a 1-D column."""
+    return jnp.concatenate([src[lo:hi] for lo, hi in bounds] or [src[:0]])

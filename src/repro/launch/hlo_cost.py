@@ -45,16 +45,9 @@ __all__ = ["HloCostModel", "analyze_hlo", "collective_bytes_from_hlo", "xla_cost
 
 
 def xla_cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions: some
-    releases return a one-element list of per-module dicts, others the dict
-    itself (and GPU backends may raise).  Always returns a plain dict."""
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``compiled.cost_analysis()`` — XLA's per-module cost dict."""
+    return dict(compiled.cost_analysis())
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1, "f8e4m3fn": 1, "f8e5m2": 1,

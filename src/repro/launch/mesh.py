@@ -38,7 +38,14 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"BEFORE importing jax (dryrun.py does this)"
         )
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devs[:need])
+    # Auto axes: the sharding rules place arrays with with_sharding_constraint,
+    # which jax refuses on the Explicit axes jax.make_mesh defaults to
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axes),
+        devices=devs[:need],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def rules_for(

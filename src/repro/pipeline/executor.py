@@ -100,8 +100,8 @@ class RunResult:
     bytes_d2h: int = 0  # device->host bytes (jax fn outputs landing back)
     device_hits: int = 0  # columns/pins served from resident device arrays
     device_evictions: int = 0  # tier entries LRU-demoted during this run
-    gather_fast: int = 0  # fragment_gather block-run fast-path calls
-    gather_fallbacks: int = 0  # non-RB-aligned gathers (RB=1 / XLA take)
+    gather_fast: int = 0  # multi-run gathers served by fragment_gather
+    gather_fallbacks: int = 0  # multi-run gathers off the tile grid (XLA slices)
     device_union_bytes: int = 0  # output bytes assembled on device
     # spill-tier mmap promotions: payload bytes page-faulted in from local
     # spill files instead of travelling through simulated GETs

@@ -92,7 +92,7 @@ def test_device_union_bitwise_equals_numpy_reference(seed, dtype, n_runs, aligne
     dt = np.dtype(dtype)
     providers = []
     for _ in range(int(rng.integers(1, 4))):
-        rows = int(rng.integers(1, 300))
+        rows = int(rng.integers(1, 3 * ROW_BLOCK))
         if dt.kind == "f":
             host = rng.standard_normal(rows).astype(dt)
         else:
@@ -137,22 +137,22 @@ def test_device_union_single_fragment_is_a_slice():
 def test_device_union_multi_interval_hits_fast_path():
     """Two aligned runs of ONE provider become a single block-run
     fragment_gather on the tiled fast path."""
-    host = np.arange(512, dtype=np.float32)
+    host = np.arange(4096, dtype=np.float32)
     prov = {"x": _pad_rows(jnp.asarray(host))}
     ledger = {}
     got = device_union(
-        [(prov, 0, 128), (prov, 256, 512)], ["x"], interpret=True, ledger=ledger
+        [(prov, 0, 1024), (prov, 2048, 4096)], ["x"], interpret=True, ledger=ledger
     )
     np.testing.assert_array_equal(
-        np.asarray(got["x"]), np.concatenate([host[0:128], host[256:512]])
+        np.asarray(got["x"]), np.concatenate([host[0:1024], host[2048:4096]])
     )
     assert ledger["gather_fast"] == 1
     assert "gather_fallbacks" not in ledger
 
 
 def test_device_union_non_aligned_counts_fallback_downgrade():
-    """Off-alignment runs still serve (RB=1-grade kernel or XLA take) but
-    the silent downgrade is counted, not hidden."""
+    """Off-tile runs still serve (XLA slices, no kernel) but the downgrade
+    is counted, not hidden."""
     host = np.arange(512, dtype=np.float32)
     prov = {"x": _pad_rows(jnp.asarray(host))}
     ledger = {}
@@ -174,19 +174,22 @@ def test_device_union_empty_runs_yield_empty_columns():
 
 # ------------------------------------------------- fragment_gather regressions
 def test_fragment_gather_tail_not_padded_into_output():
-    """R not a multiple of row_block: the tile-padded tail must never leak
-    zero rows into the output (the _pad_axis regression)."""
-    src = jnp.asarray(np.random.default_rng(0).standard_normal((64, 4)).astype(np.float32))
-    idx = np.arange(13, dtype=np.int32)  # 13 % 8 != 0
-    out = fragment_gather(src, idx, row_block=8, interpret=True)
-    assert out.shape == (13, 4)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(gather_ref(src, jnp.asarray(idx))))
+    """The output is exactly the runs: a pin's tile-padded tail never leaks
+    zero rows into it (the pin padding regression)."""
+    host = np.random.default_rng(0).standard_normal(3000).astype(np.float32)
+    src = _pad_rows(jnp.asarray(host))
+    assert src.shape == (3 * ROW_BLOCK,)
+    bounds = [(2048, 3072), (0, 1024)]
+    out = fragment_gather(src, bounds, interpret=True)
+    assert out.shape == (2048,)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(gather_ref(src, bounds)))
+    np.testing.assert_array_equal(np.asarray(out[:952]), host[2048:])
 
 
 def test_fragment_gather_rejects_out_of_range_indices():
-    src = jnp.asarray(np.zeros((10, 4), np.float32))
+    src = jnp.asarray(np.zeros(1024, np.float32))
     with pytest.raises(IndexError):
-        fragment_gather(src, np.array([0, 10], np.int32), row_block=8, interpret=True)
+        fragment_gather(src, [(0, 1024), (1024, 2048)], interpret=True)
 
 
 # ----------------------------------------------------- ChunkedTable column memo
@@ -367,11 +370,12 @@ def test_warm_run_serves_from_device_and_counts_hits(tmp_path):
 
 def test_multi_interval_window_takes_gather_fast_path(tmp_path):
     """An OR-window served from two intervals of one merged element is a
-    genuine multi-run fragment_gather — aligned bounds hit the tiled fast
-    path and the ledger says so."""
+    genuine multi-run fragment_gather — tile-aligned bounds hit the kernel
+    and the ledger says so."""
     ws = _dev_workspace(str(tmp_path / "dev"))
-    ws.run(jax_feature_project(_w(0, 1024)))
-    res = ws.run(jax_feature_project(f"{_w(0, 256)} OR {_w(512, 1024)}"))
+    ws.catalog.append("ns.raw", events_table(1024, 4096))
+    ws.run(jax_feature_project(_w(0, 4096)))
+    res = ws.run(jax_feature_project(f"{_w(0, 1024)} OR {_w(2048, 4096)}"))
     assert res.gather_fast >= 1
     assert res.bytes_h2d == 0
 

@@ -133,32 +133,52 @@ def test_chunked_ref_matches_sequential_ref():
 # --------------------------------------------------------------- gather
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
 def test_fragment_gather_contiguous_runs(dtype):
-    """Fragment-shaped access: whole aligned runs (fast tiled path)."""
-    Ns, C = 64, 40
-    src = jnp.arange(Ns * C).reshape(Ns, C).astype(dtype)
-    # two fragments: rows [16, 40) then rows [0, 24) — both 8-aligned
-    idx = np.concatenate([np.arange(16, 40), np.arange(0, 24)])
-    got = fragment_gather(src, idx, row_block=8, col_block=128, interpret=True)
-    want = gather_ref(src, jnp.asarray(idx))
+    """Fragment-shaped access: whole tile-aligned runs of a 1-D column."""
+    src = jnp.arange(8192).astype(dtype)
+    bounds = [(2048, 5120), (0, 3072)]  # out of order, overlapping
+    got = fragment_gather(src, bounds, interpret=True)
+    want = gather_ref(src, bounds)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_fragment_gather_arbitrary_rows():
-    """Non-aligned indices take the row-granular fallback."""
-    Ns, C = 33, 17
+    """Runs at random tiles, in any order and any length, gather exactly;
+    a run off the tile grid is refused (the caller serves it with XLA
+    slices — the TPU compiler has no block for it)."""
     rng = np.random.default_rng(0)
-    src = jnp.asarray(rng.standard_normal((Ns, C)), jnp.float32)
-    idx = rng.integers(0, Ns, size=29)
-    got = fragment_gather(src, idx, interpret=True)
-    want = gather_ref(src, jnp.asarray(idx))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    src = jnp.asarray(rng.standard_normal(16 * 1024), jnp.float32)
+    starts = rng.integers(0, 16, size=5)
+    bounds = [
+        (int(s) * 1024, int(s + rng.integers(1, 17 - s)) * 1024) for s in starts
+    ]
+    got = fragment_gather(src, bounds, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(gather_ref(src, bounds)))
+    with pytest.raises(ValueError):
+        fragment_gather(src, [(0, 1024), (2051, 4096)], interpret=True)
 
 
 def test_fragment_gather_empty_and_identity():
-    src = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-    idx = np.arange(8)
-    got = fragment_gather(src, idx, row_block=8, interpret=True)
+    src = jnp.arange(2048, dtype=jnp.float32)
+    got = fragment_gather(src, [(0, 2048)], interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(src))
+    assert fragment_gather(src, [(1024, 1024)], interpret=True).shape == (0,)
+
+
+def test_fragment_gather_splits_long_gathers_into_bounded_calls(monkeypatch):
+    """More blocks than one call may prefetch into SMEM: the gather runs as
+    several calls writing one aliased output, and still matches the slices."""
+    from repro.kernels.fragment_gather import kernel
+
+    monkeypatch.setattr(kernel, "MAX_GRID_STEPS", 3)
+    src = jnp.arange(8 * 1024, dtype=jnp.int32)
+    bounds = [(4096, 8192), (0, 3072), (5120, 6144)]  # 8 blocks -> 3 calls
+    idx = np.concatenate(
+        [np.arange(lo // 1024, hi // 1024, dtype=np.int32) for lo, hi in bounds]
+    )
+    got = kernel.fragment_gather_call(
+        src, jnp.asarray(idx), row_block=1024, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(gather_ref(src, bounds)))
 
 
 # --------------------------------------------------------------- dequant

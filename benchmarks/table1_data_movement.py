@@ -1,15 +1,13 @@
 """Paper Table I: moving a dataframe into a user function.
 
 Four paths, as in the paper:
-  1. fragments in (simulated) S3    — range-reads + assembly, plus the
-     latency model's simulated seconds (first-byte + bandwidth),
+  1. fragments in (simulated) S3    — range-reads + assembly,
   2. fragments on local SSD         — same decode path, no S3 latency,
   3. Arrow-analog IPC file, mmap'd  — the paper's "Arrow IPC ≈ 0 s" row,
   4. zero-copy view of a cache element — the differential cache's serving
      path (slice of a shared buffer).
 
-We report wall seconds on this host plus simulated S3 seconds; the claim
-under test is the ORDERING and the ≈0 cost of IPC/views, which is exactly
+We report wall seconds on this host; the claim under test is the ORDERING and the ≈0 cost of IPC/views, which is exactly
 what motivates the Arrow-backed cache design (paper §III-A).
 """
 
@@ -57,7 +55,7 @@ def run(rows: int = 2_000_000) -> List[Dict]:
         data = _mktable(rows)
         nbytes = data.nbytes
 
-        # --- 1) S3 fragments (with simulated object-store latency)
+        # --- 1) S3 fragments
         store = ObjectStore(os.path.join(tmp, "s3"))
         catalog = Catalog(store, rows_per_fragment=1 << 18)
         catalog.create_table("b", "t", data.schema(), "ts")
@@ -68,9 +66,8 @@ def run(rows: int = 2_000_000) -> List[Dict]:
         _consume(out)
         wall = time.perf_counter() - t0
         results.append(
-            {"source": "fragments in S3 (sim latency)", "rows": rows,
-             "gbytes": nbytes / 1e9, "wall_s": wall,
-             "total_s": wall + ex.reports[-1].simulated_seconds}
+            {"source": "fragments in S3", "rows": rows,
+             "gbytes": nbytes / 1e9, "wall_s": wall, "total_s": wall}
         )
 
         # --- 2) SSD fragments: same path, no simulated latency

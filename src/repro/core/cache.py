@@ -677,9 +677,13 @@ class DifferentialStore:
     def _merge_pair(
         self, a: CacheElement, b: CacheElement, usable_fn: Optional[UsableFn]
     ) -> CacheElement:
-        with self.tracer.span("cache.merge", signature=str(a.signature)[:16]) as sp:
+        tracer = self.tracer
+        if not tracer.enabled:
+            return self._merge_pair_inner(a, b, usable_fn)
+        with tracer.span("cache.merge", signature=str(a.signature)[:16]) as sp:
             out = self._merge_pair_inner(a, b, usable_fn)
             sp.attrs["bytes"] = out.nbytes
+            sp.attrs["rows"] = out.data.num_rows
         return out
 
     def _merge_pair_inner(
@@ -736,9 +740,12 @@ class DifferentialStore:
             # keeps hitting device across merges, uploading only residuals.
             # Best-effort: with either parent unpinned the merged element
             # just re-pins lazily on its next device consumer.
-            self.device.replicate_merge(a, b, out, a_use, b_only)
-            self._drop_device(a)
-            self._drop_device(b)
+            with self.tracer.span("cache.merge.replicate") as sp:
+                replicated = self.device.replicate_merge(a, b, out, a_use, b_only)
+                self._drop_device(a)
+                self._drop_device(b)
+                if self.tracer.enabled:
+                    sp.attrs["bytes"] = replicated
         return out
 
     def _drop_device(self, elem: CacheElement) -> None:

@@ -247,16 +247,20 @@ class DeviceTier:
         rows: int,
         *,
         replicated: bool = False,
-    ) -> None:
+    ) -> int:
         """Register already-on-device columns for ``elem_id`` (a fresh
         residual the executor just converted, or a merge replica) — no H2D
-        is counted here; the producer accounted for the transfer."""
+        is counted here; the producer accounted for the transfer.  Returns
+        the device bytes registered, padding included."""
+        total = 0
         for c, arr in arrays.items():
             padded = _pad_rows(arr)
+            total += int(padded.nbytes)
             if replicated:
                 with self.lock:
                     self.bytes_replicated += int(padded.nbytes)
             self._insert(elem_id, c, padded, rows, h2d=0, ledger=None)
+        return total
 
     def _insert(self, elem_id, column, arr, rows, *, h2d, ledger):
         with self.lock:
@@ -288,24 +292,25 @@ class DeviceTier:
                 out[c] = e.arr
         return out
 
-    def replicate_merge(self, a, b, merged, a_window, b_window) -> bool:
+    def replicate_merge(self, a, b, merged, a_window, b_window) -> int:
         """Build the merged element's device columns from its parents'
         pins (device→device fragment gather — zero H2D).  Mirrors
         ``DifferentialStore._merge_pair`` exactly: ``a`` contributes its
         rows inside ``a_window``, ``b`` inside ``b_window`` (disjoint), and
-        the merged payload is their key-ordered union.  Returns False (and
-        pins nothing) when either parent is not fully resident here."""
+        the merged payload is their key-ordered union.  Returns the device
+        bytes of the replica; 0, pinning nothing, when either parent is not
+        fully resident here."""
         cols = list(merged.columns)
         prov_a = self.element_arrays(a, cols)
         prov_b = self.element_arrays(b, cols)
         if prov_a is None or prov_b is None:
-            return False
+            return 0
         runs: List[Tuple[Any, Mapping[str, Any], int, int]] = []
         for side, window, prov in ((a, a_window, prov_a), (b, b_window, prov_b)):
             for iv, lo, hi in side.window_runs(window):
                 runs.append((iv.lo, prov, lo, hi))
         if not runs:
-            return True  # empty merge: nothing to pin, trivially replicated
+            return 0  # empty merge: nothing to pin, trivially replicated
         runs.sort(key=lambda r: r[0])
         arrays = device_union(
             [(prov, lo, hi) for _key, prov, lo, hi in runs],
@@ -313,8 +318,7 @@ class DeviceTier:
             interpret=self.interpret,
         )
         rows = sum(hi - lo for _key, _prov, lo, hi in runs)
-        self.adopt(merged.elem_id, arrays, rows, replicated=True)
-        return True
+        return self.adopt(merged.elem_id, arrays, rows, replicated=True)
 
     # -- demotion ------------------------------------------------------------
     def drop_element(self, elem_id: int) -> None:
@@ -347,6 +351,31 @@ class DeviceTier:
                     if not cols:
                         del self._by_elem[elem_id]
                 self.device_evictions += 1
+
+
+def upload_residual(
+    fresh: Table,
+    columns: Sequence[str],
+    ledger: Dict[str, int],
+    tracer: Tracer,
+    site: str,
+) -> Optional[Dict[str, Any]]:
+    """Upload a fresh residual's columns: the one H2D transfer its bytes
+    ever pay, since the arrays go to the cache insert and every later
+    consumer, post-merge ones included, serves from device.  One
+    ``device.h2d`` span (``site`` names the caller); None, uploading
+    nothing, when any column's dtype has no device analog."""
+    if not all(DeviceTier.supported(fresh.column(c).dtype) for c in columns):
+        return None
+    import jax.numpy as jnp
+
+    with tracer.span("device.h2d", site=site) as sp:
+        out = {c: jnp.asarray(fresh.column(c)) for c in columns}
+        h2d = sum(int(arr.nbytes) for arr in out.values())
+        if tracer.enabled:
+            sp.attrs["bytes"] = h2d
+    _bump(ledger, "bytes_h2d", h2d)
+    return out
 
 
 # ---------------------------------------------------------------------------

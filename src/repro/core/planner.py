@@ -58,7 +58,6 @@ class ScanReport:
     store_requests: int
     cache_chunks: int  # hit-served cache views ONLY (never the residual)
     fully_cached: bool
-    simulated_seconds: float
     residual_rows: int = 0  # rows fetched fresh from object storage
     bytes_from_spill: int = 0  # payload bytes promoted spill -> RAM for hits
     bytes_mmap: int = 0  # mmap-promoted spill payload bytes (zero-copy reads)
@@ -267,7 +266,9 @@ class ScanExecutor:
                     res_sp.attrs["rows"] = fresh.num_rows
                 fresh_dev = None
                 if dev_ok and fresh.num_rows:
-                    fresh_dev = self._to_device(fresh, proj, dev_ledger)
+                    from repro.core.device import upload_residual
+
+                    fresh_dev = upload_residual(fresh, proj, dev_ledger, self.tracer, "scan")
                     if fresh_dev is None:
                         dev_ok = False
                 insert_kwargs = {"tenant": self.tenant}
@@ -299,7 +300,6 @@ class ScanExecutor:
                 store_requests=delta.get_requests,
                 cache_chunks=hit_chunks,
                 fully_cached=plan.fully_cached,
-                simulated_seconds=delta.simulated_seconds,
                 residual_rows=residual_rows,
                 bytes_from_spill=spill_bytes,
                 bytes_mmap=delta.bytes_mmap,
@@ -377,25 +377,6 @@ class ScanExecutor:
                 out = DeviceChunkedTable(out.chunks, arrays)
         return out
 
-    @staticmethod
-    def _to_device(fresh: Table, columns: Sequence[str], ledger: Dict[str, int]):
-        """Upload a fresh residual's columns (the one H2D transfer the
-        residual ever pays: the arrays are handed to the cache insert so
-        future consumers — including post-merge ones — hit device).  None
-        when any column's dtype has no device analog."""
-        from repro.core.device import DeviceTier
-
-        if not all(DeviceTier.supported(fresh.column(c).dtype) for c in columns):
-            return None
-        import jax.numpy as jnp
-
-        out = {}
-        for c in columns:
-            arr = jnp.asarray(fresh.column(c))
-            ledger["bytes_h2d"] = ledger.get("bytes_h2d", 0) + int(arr.nbytes)
-            out[c] = arr
-        return out
-
     # -- accounting ----------------------------------------------------------
     def total_bytes_processed(self) -> int:
         return sum(r.bytes_from_store for r in self.reports)
@@ -465,7 +446,7 @@ class ResultCachingExecutor:
             self.inner.reports.append(
                 ScanReport(table, snapshot.snapshot_id, tuple(sorted(columns)),
                            key[3], 0, self._memo[key].nbytes, 0,
-                           len(self._memo[key].chunks), True, 0.0)
+                           len(self._memo[key].chunks), True)
             )
             return self._memo[key]
         out = self.inner.scan(table, columns, window, snapshot_id, predicate, sorted_output)

@@ -83,7 +83,6 @@ class RunResult:
     outputs: Dict[str, Table]
     bytes_from_store: int
     bytes_from_cache: int
-    simulated_seconds: float
     wall_seconds: float
     plan: PhysicalPlan
     # incremental-engine ledger: how much work the user functions actually did
@@ -348,7 +347,6 @@ class Workspace:
             outputs=results,
             bytes_from_store=delta.bytes_read,
             bytes_from_cache=sum(r.bytes_from_cache for r in scan_reports),
-            simulated_seconds=delta.simulated_seconds,
             wall_seconds=time.perf_counter() - t0,
             plan=plan,
             rows_to_user_fns=sum(s["fresh_rows"] for s in node_stats.values()),
@@ -489,7 +487,7 @@ class Workspace:
                 kwargs[arg] = results[ref]
             rows += kwargs[arg].num_rows
         dev_ledger: Dict[str, int] = {}
-        out = _invoke(fn, step.runtime, kwargs, dev_ledger)
+        out = _invoke(fn, step.runtime, kwargs, self.tracer, dev_ledger)
         if expl.enabled:
             expl.record(
                 Decision(
@@ -628,7 +626,7 @@ class Workspace:
             kwargs = self._residual_inputs(
                 step, plan, results, IntervalSet.empty_set(), snapshots, expl
             )
-            out = _invoke(fn, step.runtime, kwargs)
+            out = _invoke(fn, step.runtime, kwargs, self.tracer)
             return self._windowed_output(step, kwargs, out), {
                 "fresh_rows": 0,
                 "cached_rows": 0,
@@ -784,12 +782,18 @@ class Workspace:
                             fresh = hit_chunks[0].slice(0, 0)
                         else:
                             fresh_rows = total_in
-                            out = _invoke(fn, step.runtime, kwargs, dev_ledger)
+                            out = _invoke(
+                                fn, step.runtime, kwargs, self.tracer, dev_ledger
+                            )
                             fresh = self._windowed_output(step, kwargs, out)
                         res_sp.attrs["rows"] = fresh_rows
                     fresh_dev = None
                     if dev_ok and fresh.num_rows:
-                        fresh_dev = _fresh_to_device(fresh, dev_ledger)
+                        from repro.core.device import upload_residual
+
+                        fresh_dev = upload_residual(
+                            fresh, fresh.column_names, dev_ledger, self.tracer, "fresh"
+                        )
                         if fresh_dev is None:
                             dev_ok = False
                     if len(snapshots) == 1:
@@ -844,21 +848,22 @@ class Workspace:
                             memo[t] = None
                 return {t: memo[t] for t in snapshots}
 
-            self.explainer.classify_node(
-                expl,
-                node=step.model,
-                kind=step.incremental,
-                sig_parts=step.sig_parts,
-                signature=step.signature,
-                window=step.window,
-                residual=mplan.residual,
-                elements=elem_views,
-                snapshots=snapshots,
-                current_ids=current_ids,
-                rows=fresh_rows,
-                tier="ram+spill" if spill_bytes else ("ram" if cached_rows else ""),
-                quarantined=quarantined,
-            )
+            with self.tracer.span("node.explain", model=step.model):
+                self.explainer.classify_node(
+                    expl,
+                    node=step.model,
+                    kind=step.incremental,
+                    sig_parts=step.sig_parts,
+                    signature=step.signature,
+                    window=step.window,
+                    residual=mplan.residual,
+                    elements=elem_views,
+                    snapshots=snapshots,
+                    current_ids=current_ids,
+                    rows=fresh_rows,
+                    tier="ram+spill" if spill_bytes else ("ram" if cached_rows else ""),
+                    quarantined=quarantined,
+                )
         self.metrics.counter("residual_rows", kind=step.incremental).inc(
             fresh_rows
         )
@@ -1190,36 +1195,11 @@ def _to_table(value: Any) -> Table:
     raise TypeError(f"model must return Table/ChunkedTable/dict, got {type(value)}")
 
 
-def _fresh_to_device(
-    fresh: Table, ledger: Optional[Dict[str, int]] = None
-) -> Optional[Dict[str, Any]]:
-    """Upload every column of a fresh residual (the one H2D transfer its
-    bytes ever pay — the arrays go to the cache insert, so future consumers
-    and post-merge elements serve from device).  None when any column's
-    dtype has no device analog."""
-    from repro.core.device import DeviceTier
-
-    if not all(
-        DeviceTier.supported(fresh.column(c).dtype) for c in fresh.column_names
-    ):
-        return None
-    import jax.numpy as jnp
-
-    out: Dict[str, Any] = {}
-    h2d = 0
-    for c in fresh.column_names:
-        arr = jnp.asarray(fresh.column(c))
-        h2d += int(arr.nbytes)
-        out[c] = arr
-    if ledger is not None:
-        ledger["bytes_h2d"] = ledger.get("bytes_h2d", 0) + h2d
-    return out
-
-
 def _invoke(
     fn: Callable,
     runtime: str,
     kwargs: Dict[str, Any],
+    tracer: Tracer,
     ledger: Optional[Dict[str, int]] = None,
 ) -> Table:
     if runtime == "numpy":
@@ -1227,8 +1207,11 @@ def _invoke(
             k: (v.combine() if isinstance(v, ChunkedTable) else v)
             for k, v in kwargs.items()
         }
-        return _to_table(fn(**prepared))
+        with tracer.span("node.call", runtime=runtime):
+            out = fn(**prepared)
+        return _to_table(out)
     if runtime == "jax":
+        import jax
         import jax.numpy as jnp
 
         def _count(key: str, by: int) -> None:
@@ -1236,33 +1219,51 @@ def _invoke(
                 ledger[key] = ledger.get(key, 0) + by
 
         prepared = {}
-        for k, v in kwargs.items():
-            # device-resident inputs (DeviceTable / DeviceChunkedTable) hand
-            # their columns straight to the fn — zero host round-trips; any
-            # column without a device copy falls back to the H2D conversion
-            devcols = getattr(v, "device_columns", None) or {}
-            names = v.column_names
-            cols: Dict[str, Any] = {}
-            host = None
-            for name in names:
-                arr = devcols.get(name)
-                if arr is not None:
-                    _count("device_hits", 1)
-                else:
-                    if host is None:
-                        host = v.combine() if isinstance(v, ChunkedTable) else v
-                    arr = jnp.asarray(host.column(name))
-                    _count("bytes_h2d", int(arr.nbytes))
-                cols[name] = arr
-            prepared[k] = cols
-        out = fn(**prepared)
+        h2d = 0
+        with tracer.span("device.h2d", site="input") as sp:
+            for k, v in kwargs.items():
+                # device-resident inputs (DeviceTable / DeviceChunkedTable)
+                # hand their columns straight to the fn — zero host
+                # round-trips; any column without a device copy falls back
+                # to the H2D conversion
+                devcols = getattr(v, "device_columns", None) or {}
+                names = v.column_names
+                cols: Dict[str, Any] = {}
+                host = None
+                for name in names:
+                    arr = devcols.get(name)
+                    if arr is not None:
+                        _count("device_hits", 1)
+                    else:
+                        if host is None:
+                            host = v.combine() if isinstance(v, ChunkedTable) else v
+                        arr = jnp.asarray(host.column(name))
+                        nbytes = int(arr.nbytes)
+                        h2d += nbytes
+                        _count("bytes_h2d", nbytes)
+                    cols[name] = arr
+                prepared[k] = cols
+            if tracer.enabled:
+                sp.attrs["bytes"] = h2d
+        with tracer.span("node.call", runtime=runtime):
+            out = fn(**prepared)
         if not isinstance(out, dict):
             raise TypeError("jax models must return {column: jnp.ndarray}")
+        if tracer.enabled:
+            # the host's wait for the device queue, apart from the copy
+            with tracer.span("device.wait"):
+                jax.block_until_ready(out)
         host_out = {}
-        for k, v in out.items():
-            arr = np.asarray(v)
-            _count("bytes_d2h", int(arr.nbytes))
-            host_out[k] = arr
+        d2h = 0
+        with tracer.span("device.d2h") as sp:
+            for k, v in out.items():
+                arr = np.asarray(v)
+                nbytes = int(arr.nbytes)
+                d2h += nbytes
+                _count("bytes_d2h", nbytes)
+                host_out[k] = arr
+            if tracer.enabled:
+                sp.attrs["bytes"] = d2h
         return Table(host_out)
     raise ValueError(f"unknown runtime {runtime!r}")
 

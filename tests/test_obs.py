@@ -323,6 +323,127 @@ def test_service_report_metrics_text(tmp_path):
         )
 
 
+# --------------------------------------------- spans of the device-tier loop
+RUN_COUNTERS = (
+    "bytes_from_store",
+    "bytes_from_cache",
+    "rows_to_user_fns",
+    "bytes_from_model_cache",
+    "bytes_from_spill",
+    "coalesced_waits",
+    "bytes_h2d",
+    "bytes_d2h",
+    "device_hits",
+    "device_evictions",
+    "gather_fast",
+    "gather_fallbacks",
+    "device_union_bytes",
+    "bytes_mmap",
+)
+
+
+def _edit_loop(root, tracer, device=True):
+    """A tiny edit loop through ``Workspace.run``: cold, widen, rerun,
+    shift off the tile grid, append, widen.  Yields each run's result with
+    the spans it recorded (the tracer is cleared before every run)."""
+    from repro.core.device import DeviceTier
+    from test_device import SCHEMA as DEV_SCHEMA
+    from test_device import events_table, jax_feature_project
+
+    ws = Workspace(
+        root,
+        rows_per_fragment=128,
+        device=DeviceTier(interpret=True) if device else None,
+        tracer=tracer,
+    )
+    ws.catalog.create_table("ns", "raw", DEV_SCHEMA, "eventTime")
+    ws.catalog.append("ns.raw", events_table(0, 1024))
+    windows = [(0, 512), (0, 768), (0, 768), (37, 900), None, (37, 1280)]
+    for w in windows:
+        if w is None:
+            ws.catalog.append("ns.raw", events_table(1024, 1280))
+            continue
+        tracer.clear()
+        res = ws.run(jax_feature_project(f"eventTime >= {w[0]} AND eventTime < {w[1]}"))
+        yield res, [sp for root in tracer.roots() for sp in root.walk()]
+
+
+def _parents(tracer_roots):
+    out = []
+    for root in tracer_roots:
+        stack = [(root, None)]
+        while stack:
+            sp, parent = stack.pop()
+            out.append((sp, parent))
+            stack.extend((c, sp) for c in sp.children)
+    return out
+
+
+@pytest.mark.parametrize("device", [True, False])
+def test_merge_replicate_span_only_under_merge_with_a_device_tier(tmp_path, device):
+    tr = Tracer()
+    merges, replicated = 0, []
+    for _res, _spans in _edit_loop(str(tmp_path / "ws"), tr, device=device):
+        for sp, parent in _parents(tr.roots()):
+            if sp.name == "cache.merge":
+                merges += 1
+                assert sp.attrs["bytes"] > 0 and sp.attrs["rows"] > 0
+            elif sp.name == "cache.merge.replicate":
+                assert parent is not None and parent.name == "cache.merge"
+                replicated.append(sp.attrs["bytes"])
+    assert merges > 0
+    assert len(replicated) == (merges if device else 0)
+    # scan-cache elements hold no pins here (their scans feed host code), so
+    # only the model store's merges replicate any bytes
+    if device:
+        assert max(replicated) > 0
+
+
+def test_transfer_spans_add_up_to_the_run_ledger(tmp_path):
+    """Every byte a run counts crossing the host link lies under one span
+    name: ``device.h2d`` up (pins, fresh residuals, jax inputs) and
+    ``device.d2h`` down, each ``device.d2h`` after its ``device.wait``."""
+    tr = Tracer()
+    runs = 0
+    for res, spans in _edit_loop(str(tmp_path / "ws"), tr):
+        runs += 1
+        names = [sp.name for sp in spans]
+        h2d = sum(sp.attrs["bytes"] for sp in spans if sp.name == "device.h2d")
+        d2h = sum(sp.attrs["bytes"] for sp in spans if sp.name == "device.d2h")
+        assert h2d == res.bytes_h2d
+        assert d2h == res.bytes_d2h > 0
+        assert names.count("device.wait") == names.count("device.d2h") == names.count("node.call")
+        assert {sp.attrs["runtime"] for sp in spans if sp.name == "node.call"} == {"jax"}
+        assert names.count("node.explain") == 1  # the one rowwise node
+        for sp, parent in _parents(tr.roots()):
+            if sp.name == "device.wait":
+                i = parent.children.index(sp)
+                assert parent.children[i + 1].name == "device.d2h"
+                assert parent.children[i - 1].name == "node.call"
+    assert runs == 5
+    sites = {sp.attrs.get("site") for sp in tr.find("device.h2d")}
+    assert {"input", "fresh"} <= sites
+
+
+def test_disabled_tracer_records_nothing_and_counts_the_same(tmp_path):
+    on, off = Tracer(), Tracer(enabled=False)
+    traced = list(_edit_loop(str(tmp_path / "on"), on))
+    quiet = list(_edit_loop(str(tmp_path / "off"), off))
+    assert off.roots() == [] and all(spans == [] for _res, spans in quiet)
+    assert len(traced) == len(quiet)
+    for (a, _), (b, _) in zip(traced, quiet):
+        assert {k: getattr(a, k) for k in RUN_COUNTERS} == {
+            k: getattr(b, k) for k in RUN_COUNTERS
+        }
+        assert a.node_stats == b.node_stats
+        for name, table in a.outputs.items():
+            for col in table.column_names:
+                np.testing.assert_array_equal(
+                    np.asarray(table.column(col)),
+                    np.asarray(b.outputs[name].column(col)),
+                )
+
+
 # ---------------------------------------------------------------- explainer
 def test_edit_matrix_diagnoses_all_causes(tmp_path):
     from repro.explain import edit_matrix_demo

@@ -46,7 +46,7 @@ from typing import TYPE_CHECKING, Callable, Dict, Hashable, List, Optional, Sequ
 
 import numpy as np
 
-from repro.core.columnar import Table, concat_tables
+from repro.core.columnar import Table
 from repro.core.intervals import Interval, IntervalSet
 from repro.core.scan import Scan, scan_cost_bytes
 from repro.obs.metrics import MetricAttr, Metrics
@@ -679,16 +679,19 @@ class DifferentialStore:
     ) -> CacheElement:
         tracer = self.tracer
         if not tracer.enabled:
-            return self._merge_pair_inner(a, b, usable_fn)
+            return self._merge_pair_inner(a, b, usable_fn)[0]
         with tracer.span("cache.merge", signature=str(a.signature)[:16]) as sp:
-            out = self._merge_pair_inner(a, b, usable_fn)
+            out, n_runs = self._merge_pair_inner(a, b, usable_fn)
             sp.attrs["bytes"] = out.nbytes
             sp.attrs["rows"] = out.data.num_rows
+            sp.attrs["runs"] = n_runs
         return out
 
     def _merge_pair_inner(
         self, a: CacheElement, b: CacheElement, usable_fn: Optional[UsableFn]
-    ) -> CacheElement:
+    ) -> Tuple[CacheElement, int]:
+        """The merged element, and the number of payload runs it was
+        concatenated from."""
         # The two sides may have been assembled under DIFFERENT snapshots, so
         # each contributes only its usable window under the current one —
         # merging raw windows would let rows from dropped fragments (or
@@ -700,11 +703,26 @@ class DifferentialStore:
         b_use = usable_fn(b) if usable_fn is not None else b.window
         b_only = b_use.difference(a_use)
         window = a_use.union(b_use)
-        parts = a.slice_window(a_use, a.columns) + b.slice_window(b_only, b.columns)
-        if parts:
-            data = concat_tables(parts).sort_by(a.sort_key)
-        else:
+        # Each side's payload is key-sorted and a_use, b_only are disjoint, so
+        # the runs ordered by interval start are the merged key order — and
+        # the exact stable-sort order, since equal keys never span two runs.
+        # The device replica is built from this same list.
+        runs = sorted(
+            [(iv.lo, a, lo, hi) for iv, lo, hi in a.window_runs(a_use)]
+            + [(iv.lo, b, lo, hi) for iv, lo, hi in b.window_runs(b_only)],
+            key=lambda r: r[0],
+        )
+        views = [side.data.select(a.columns).slice(lo, hi) for _, side, lo, hi in runs]
+        if not views:
             data = a.data.slice(0, 0)
+        elif len(runs) == 1 and views[0].num_rows == runs[0][1].data.num_rows:
+            data = views[0]  # the whole of one side's payload: nothing dead to pin
+        else:
+            # a fresh contiguous payload (concatenate copies even one run), so
+            # no slice keeps a larger parent buffer alive that nbytes misses
+            data = Table(
+                {c: np.concatenate([v.column(c) for v in views]) for c in a.columns}
+            )
         # keep only pins that back rows a side actually CONTRIBUTED: a pin of
         # a's for a region a did not contribute (its usable window excluded
         # it — e.g. the fragment was dropped by a newer snapshot) must not
@@ -741,12 +759,12 @@ class DifferentialStore:
             # Best-effort: with either parent unpinned the merged element
             # just re-pins lazily on its next device consumer.
             with self.tracer.span("cache.merge.replicate") as sp:
-                replicated = self.device.replicate_merge(a, b, out, a_use, b_only)
+                replicated = self.device.replicate_merge(a, b, out, runs)
                 self._drop_device(a)
                 self._drop_device(b)
                 if self.tracer.enabled:
                     sp.attrs["bytes"] = replicated
-        return out
+        return out, len(runs)
 
     def _drop_device(self, elem: CacheElement) -> None:
         """Forget an element's device pins (it merged away or left the

@@ -292,33 +292,28 @@ class DeviceTier:
                 out[c] = e.arr
         return out
 
-    def replicate_merge(self, a, b, merged, a_window, b_window) -> int:
+    def replicate_merge(self, a, b, merged, runs) -> int:
         """Build the merged element's device columns from its parents'
-        pins (device→device fragment gather — zero H2D).  Mirrors
-        ``DifferentialStore._merge_pair`` exactly: ``a`` contributes its
-        rows inside ``a_window``, ``b`` inside ``b_window`` (disjoint), and
-        the merged payload is their key-ordered union.  Returns the device
-        bytes of the replica; 0, pinning nothing, when either parent is not
-        fully resident here."""
+        pins (device→device fragment gather — zero H2D).  ``runs`` is the
+        list ``DifferentialStore._merge_pair`` concatenated the host payload
+        from: ``(key, side, lo, hi)`` row runs of ``a`` and ``b`` in merged
+        key order, so host and device copies follow one list.  Returns the
+        device bytes of the replica; 0, pinning nothing, when either parent
+        is not fully resident here."""
         cols = list(merged.columns)
         prov_a = self.element_arrays(a, cols)
         prov_b = self.element_arrays(b, cols)
         if prov_a is None or prov_b is None:
             return 0
-        runs: List[Tuple[Any, Mapping[str, Any], int, int]] = []
-        for side, window, prov in ((a, a_window, prov_a), (b, b_window, prov_b)):
-            for iv, lo, hi in side.window_runs(window):
-                runs.append((iv.lo, prov, lo, hi))
         if not runs:
             return 0  # empty merge: nothing to pin, trivially replicated
-        runs.sort(key=lambda r: r[0])
+        prov = {a.elem_id: prov_a, b.elem_id: prov_b}
         arrays = device_union(
-            [(prov, lo, hi) for _key, prov, lo, hi in runs],
+            [(prov[side.elem_id], lo, hi) for _key, side, lo, hi in runs],
             cols,
             interpret=self.interpret,
         )
-        rows = sum(hi - lo for _key, _prov, lo, hi in runs)
-        return self.adopt(merged.elem_id, arrays, rows, replicated=True)
+        return self.adopt(merged.elem_id, arrays, merged.data.num_rows, replicated=True)
 
     # -- demotion ------------------------------------------------------------
     def drop_element(self, elem_id: int) -> None:

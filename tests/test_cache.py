@@ -12,12 +12,13 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import NoCache, ScanCache
-from repro.core.cache import DifferentialCache
-from repro.core.columnar import ChunkedTable, Table
+from repro.core.cache import DifferentialCache, DifferentialStore
+from repro.core.columnar import ChunkedTable, Table, concat_tables
 from repro.core.intervals import IntervalSet
 from repro.core.planner import ResultCachingExecutor, ScanExecutor
 from repro.lake.catalog import Catalog
 from repro.lake.s3sim import ObjectStore
+from repro.obs import Tracer
 
 SCHEMA = {"eventTime": "<i8", "c1": "<f8", "c2": "<f8", "c3": "<i8"}
 
@@ -336,6 +337,82 @@ def test_merge_after_overwrite_drops_stale_rows(env):
         assert got == want
     got = rows_of(ex.scan("ns.raw", ["c1"], IntervalSet.of((0, 256))), ["c1"])
     assert got == reference_rows(store, catalog, ["c1"], IntervalSet.of((0, 256)))
+
+
+# ------------------------------------------------ sort-free merge payloads
+def _payload(pairs, seed, dup):
+    """A key-sorted payload over ``pairs``: int64 key ``k`` (each key twice
+    when ``dup``), and value columns of three dtypes, one NaN included."""
+    keys = np.concatenate([np.arange(lo, hi, dtype=np.int64) for lo, hi in pairs])
+    if dup:
+        keys = np.repeat(keys, 2)
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal(keys.size)
+    x[keys.size // 3] = np.nan
+    return Table({
+        "k": keys,
+        "x": x,
+        "y": rng.standard_normal(keys.size).astype(np.float32),
+        "z": rng.integers(-50, 50, keys.size).astype(np.int32),
+    })
+
+
+@pytest.mark.parametrize(
+    "a_pairs, b_pairs, a_bad, b_bad, dup",
+    [
+        pytest.param(((0, 64),), ((64, 128),), (), (), False, id="append"),
+        pytest.param(((64, 128),), ((0, 64),), (), (), False, id="left"),
+        pytest.param(((0, 32), (64, 96)), ((32, 64),), (), (), False, id="split"),
+        pytest.param(((0, 128),), ((32, 64),), (), (), False, id="b_only_empty"),
+        pytest.param(((0, 64),), ((48, 128),), ((16, 40),), (), False,
+                     id="a_partly_invalidated"),
+        pytest.param(((0, 32),), ((32, 64),), (), (), True, id="duplicate_keys"),
+        pytest.param(((0, 64),), ((32, 96),), ((0, 64),), ((80, 96),), False,
+                     id="single_partial_run"),
+    ],
+)
+def test_merge_equals_concat_then_sort(a_pairs, b_pairs, a_bad, b_bad, dup):
+    """A merge concatenates the sides' disjoint key-ordered runs in window
+    order; the payload must equal, bitwise and column for column, the
+    stable sort of the same parts, and own its buffers unless it is one
+    side's whole payload."""
+    tracer = Tracer()
+    store = DifferentialStore(tracer=tracer)
+    sides = {}
+
+    def usable(e):
+        bad = a_bad if e is sides.get("a") else b_bad
+        return e.window.difference(IntervalSet.of(*bad))
+
+    def insert(name, pairs, seed):
+        sides[name] = store.insert_window(
+            signature="s", table="t", sort_key="k", window=IntervalSet.of(*pairs),
+            data=_payload(pairs, seed, dup), usable_fn=usable,
+        )
+
+    insert("a", a_pairs, 1)
+    insert("b", b_pairs, 2)
+    a, b = sides["a"], sides["b"]
+    a_use = usable(a)
+    b_only = usable(b).difference(a_use)
+    parts = a.slice_window(a_use, a.columns) + b.slice_window(b_only, b.columns)
+    want = concat_tables(parts).sort_by("k")
+
+    (merged,) = store.elements("s")
+    assert merged.window == a_use.union(usable(b))
+    got = merged.data
+    assert got.column_names == want.column_names == a.columns
+    for c in want.column_names:
+        assert got.column(c).dtype == want.column(c).dtype
+        assert got.column(c).tobytes() == want.column(c).tobytes(), c
+    (span,) = tracer.find("cache.merge")
+    assert span.attrs["runs"] == len(parts)
+    whole_side = len(parts) == 1 and parts[0].num_rows in (
+        a.data.num_rows, b.data.num_rows)
+    if not whole_side:
+        for c in got.column_names:
+            for side in (a, b):
+                assert not np.shares_memory(got.column(c), side.data.column(c))
 
 
 # --------------------------------------------------------- property testing

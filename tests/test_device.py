@@ -253,49 +253,84 @@ def test_device_tier_drop_element_forgets_all_pins():
 
 # ----------------------------------------- store integration: merge replication
 def _insert(store, sig, lo, hi, seed=0):
+    return _insert_pairs(store, sig, ((lo, hi),), seed)
+
+
+def _insert_pairs(store, sig, pairs, seed=0, on_device=False):
+    """Insert an element over the windows ``pairs``; ``on_device`` registers
+    its payload with the device tier as a residual computed there."""
+    keys = np.concatenate([np.arange(lo, hi, dtype=np.int64) for lo, hi in pairs])
+    data = Table({
+        "k": keys,
+        "x": np.random.default_rng(seed + int(keys[0])).standard_normal(keys.size),
+    })
     return store.insert_window(
         signature=sig, table="t", sort_key="k",
-        window=IntervalSet([Interval(lo, hi)]),
-        data=Table({
-            "k": np.arange(lo, hi, dtype=np.int64),
-            "x": np.random.default_rng(seed + lo).standard_normal(hi - lo),
-        }),
+        window=IntervalSet.of(*pairs), data=data,
+        device_arrays=(
+            {c: jnp.asarray(data.column(c)) for c in data.column_names}
+            if on_device else None
+        ),
     )
 
 
-def test_merge_replicates_pins_device_to_device():
+@pytest.mark.parametrize(
+    "a_pairs, b_pairs",
+    [
+        pytest.param(((0, 64),), ((64, 128),), id="append"),
+        pytest.param(((0, 32), (64, 96)), ((32, 64),), id="split"),
+    ],
+)
+def test_merge_replicates_pins_device_to_device(a_pairs, b_pairs):
     """Merging two pinned elements rebuilds the merged pin by device→device
-    gather: zero new H2D, bytes_replicated > 0, parents dropped."""
+    gather: zero new H2D, bytes_replicated > 0, parents dropped, and the
+    replica equals the host payload row for row."""
     tier = DeviceTier(interpret=True)
     store = DifferentialStore(device=tier)
-    a = _insert(store, "s", 0, 64)
+    a = _insert_pairs(store, "s", a_pairs)
     tier.pin_columns(a, ["k", "x"])
     h2d_before = tier.stats()["bytes_h2d"]
+    whole = IntervalSet.of(*a_pairs).union(IntervalSet.of(*b_pairs))
     plan = store.plan_window(
-        "s", IntervalSet([Interval(0, 128)]), (), lambda w: w.measure(),
-        device_consumer=True,
+        "s", whole, (), lambda w: w.measure(), device_consumer=True,
     )
-    assert plan.residual.to_pairs() == ((64, 128),)
-    fresh = Table({
-        "k": np.arange(64, 128, dtype=np.int64),
-        "x": np.random.default_rng(1).standard_normal(64),
-    })
-    dev_arrays = {c: jnp.asarray(fresh.column(c)) for c in fresh.column_names}
-    store.insert_window(
-        signature="s", table="t", sort_key="k",
-        window=IntervalSet([Interval(64, 128)]), data=fresh,
-        device_arrays=dev_arrays,
-    )
+    assert plan.residual.to_pairs() == b_pairs
+    _insert_pairs(store, "s", b_pairs, seed=1, on_device=True)
     (merged,) = store.elements("s")
-    assert merged.window.to_pairs() == ((0, 128),)
+    assert merged.window == whole
     stats = tier.stats()
     assert stats["bytes_h2d"] == h2d_before, "merge must not upload"
     assert stats["bytes_replicated"] > 0
+    assert tier.element_arrays(a, ["k", "x"]) is None, "parents dropped"
     arrays = tier.element_arrays(merged, ["k", "x"])
     assert arrays is not None
+    for c in ("k", "x"):
+        np.testing.assert_array_equal(
+            np.asarray(arrays[c][: merged.data.num_rows]),
+            np.asarray(jnp.asarray(merged.data.column(c))),
+        )
+
+
+def test_merge_never_sorts(monkeypatch):
+    """A merge through ``insert_window`` concatenates key-ordered runs; it
+    never sorts the merged payload, on the host or for the device replica."""
+    def refuse(self, name):
+        raise AssertionError("a merge sorted its payload")
+
+    monkeypatch.setattr(Table, "sort_by", refuse)
+    tier = DeviceTier(interpret=True)
+    store = DifferentialStore(device=tier)
+    a = _insert_pairs(store, "s", ((0, 32), (64, 96)))
+    tier.pin_columns(a, ["k", "x"])
+    b = _insert_pairs(store, "s", ((32, 64),), seed=7, on_device=True)
+    (merged,) = store.elements("s")
+    assert merged.window.to_pairs() == ((0, 96),)
+    assert tier.stats()["bytes_replicated"] > 0
+    np.testing.assert_array_equal(merged.data.column("k"), np.arange(96))
     np.testing.assert_array_equal(
-        np.asarray(arrays["x"][: merged.data.num_rows]),
-        np.asarray(jnp.asarray(merged.data.column("x"))),
+        merged.data.column("x"),
+        np.concatenate([a.data.column("x")[:32], b.data.column("x"),
+                        a.data.column("x")[32:]]),
     )
 
 

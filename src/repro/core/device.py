@@ -32,12 +32,23 @@ yields bit-identical arrays to the host path's concatenate-then-``asarray``.
 ``tests/test_device.py`` property-checks this across dtypes and window
 shapes; the edit-matrix sweep holds it across every warm/cold edit pair.
 
+Bounded shapes: eager jax compiles a program per array length, and the
+layouts a union takes follow the order its cache's writers planned in.
+One writer can replay them ahead of time; tenants of a shared service
+cannot.  ``DeviceTier(bounded=True)`` therefore holds every array at a
+power-of-two length of at least :data:`BOUNDED_MIN_ROWS` rows (padded on
+the host before the copy) and assembles unions and merge replicas by one
+jitted placement per pair of lengths, so what it runs is a closed set that
+:meth:`DeviceTier.warm` compiles before any request.  Its unions never take
+the ``fragment_gather`` kernel, whose programs follow the run layout.
+
 Everything here is advisory: any unsupported dtype, non-jax runtime, or
 missing pin falls back to the numpy path with no semantic change.
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -49,6 +60,8 @@ from repro.obs.trace import Tracer, get_tracer
 
 __all__ = [
     "ROW_BLOCK",
+    "BOUNDED_MIN_ROWS",
+    "bounded_rows",
     "DeviceTier",
     "DeviceTable",
     "DeviceChunkedTable",
@@ -59,6 +72,14 @@ __all__ = [
 # (8, 128) tiles of its lane-dense view, so any tile-aligned run of a pin is
 # a run of whole fragment_gather blocks (= fragment_gather's TILE_ROWS)
 ROW_BLOCK = 1024
+
+# the shortest array a bounded tier holds; every other is twice a shorter
+BOUNDED_MIN_ROWS = 1 << 16
+
+
+def bounded_rows(rows: int) -> int:
+    """The length a bounded tier holds ``rows`` rows at."""
+    return max(BOUNDED_MIN_ROWS, 1 << (rows - 1).bit_length())
 
 
 def _bump(ledger: Optional[Dict[str, int]], key: str, by: int = 1) -> None:
@@ -73,6 +94,41 @@ def _pad_rows(arr, mult: int = ROW_BLOCK):
     if pad == 0:
         return arr
     return jnp.pad(arr, (0, pad))
+
+
+def _upload(col: np.ndarray, bounded: bool):
+    """``col`` on the device: padded there to whole tiles, or for a bounded
+    tier padded on the host to :func:`bounded_rows` first, so the copy is
+    the only operation.  Returns the array and the bytes copied."""
+    import jax
+    import jax.numpy as jnp
+
+    if not bounded:
+        arr = _pad_rows(jnp.asarray(col))
+        return arr, int(np.dtype(arr.dtype).itemsize) * int(col.shape[0])
+    host = np.zeros(bounded_rows(len(col)), jax.dtypes.canonicalize_dtype(col.dtype))
+    host[: len(col)] = col
+    return jax.device_put(host), int(host.nbytes)
+
+
+@functools.lru_cache(maxsize=None)
+def _placer():
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def place(acc, src, lo, off, n):
+        """``acc`` with rows ``[off, off + n)`` set to ``src[lo:lo + n]``;
+        the offsets are operands, so one program serves every run between
+        arrays of these two lengths."""
+        size = acc.shape[0]
+        zeros = jnp.zeros(size, src.dtype)
+        window = lax.dynamic_slice(jnp.concatenate([src, zeros]), (lo,), (size,))
+        shifted = lax.dynamic_slice(jnp.concatenate([zeros, window]), (size - off,), (size,))
+        i = lax.iota(jnp.int32, size)
+        return jnp.where((i >= off) & (i < off + n), shifted, acc)
+
+    return jax.jit(place)
 
 
 class _DeviceEntry:
@@ -90,6 +146,8 @@ class DeviceTier:
 
     ``interpret=None`` auto-selects Pallas interpret mode off-TPU (the
     kernel wrapper's convention); tests force ``interpret=True``.
+    ``bounded`` holds arrays at :func:`bounded_rows` lengths (module
+    docstring).
     """
 
     # ledger (surfaced through SharedStore.stats() / ScanReport / RunResult);
@@ -101,10 +159,14 @@ class DeviceTier:
     bytes_replicated = MetricAttr("device_bytes_replicated")  # d2d merge bytes
 
     def __init__(
-        self, max_bytes: Optional[int] = None, interpret: Optional[bool] = None
+        self,
+        max_bytes: Optional[int] = None,
+        interpret: Optional[bool] = None,
+        bounded: bool = False,
     ):
         self.max_bytes = max_bytes
         self.interpret = interpret
+        self.bounded = bounded
         self.lock = threading.RLock()
         self._entries: Dict[Tuple[int, str], _DeviceEntry] = {}
         self._by_elem: Dict[int, set] = {}
@@ -191,11 +253,8 @@ class DeviceTier:
         col = data.column(column)
         if not self.supported(col.dtype):
             return None
-        import jax.numpy as jnp
-
         with self.tracer.span("device.h2d", elem=elem.elem_id, column=column) as sp:
-            arr = _pad_rows(jnp.asarray(col))
-            h2d = int(np.dtype(arr.dtype).itemsize) * int(col.shape[0])
+            arr, h2d = _upload(col, self.bounded)
             sp.attrs["bytes"] = h2d
         return self._insert(
             elem.elem_id, column, arr, int(col.shape[0]), h2d=h2d, ledger=ledger
@@ -220,8 +279,6 @@ class DeviceTier:
         """Upload every supported column of ``table`` under ``elem_id`` —
         the spill tier's straight-to-device promotion (mmap → H2D once).
         Returns True when all columns landed."""
-        import jax.numpy as jnp
-
         ok = True
         with self.tracer.span("device.h2d", elem=elem_id) as sp:
             total = 0
@@ -233,8 +290,7 @@ class DeviceTier:
                 with self.lock:
                     if (elem_id, c) in self._entries:
                         continue
-                arr = _pad_rows(jnp.asarray(col))
-                h2d = int(np.dtype(arr.dtype).itemsize) * int(col.shape[0])
+                arr, h2d = _upload(col, self.bounded)
                 total += h2d
                 self._insert(elem_id, c, arr, int(col.shape[0]), h2d=h2d, ledger=ledger)
             sp.attrs["bytes"] = total
@@ -254,7 +310,8 @@ class DeviceTier:
         the device bytes registered, padding included."""
         total = 0
         for c, arr in arrays.items():
-            padded = _pad_rows(arr)
+            # a bounded tier's arrays come at bounded_rows lengths already
+            padded = arr if self.bounded else _pad_rows(arr)
             total += int(padded.nbytes)
             if replicated:
                 with self.lock:
@@ -312,8 +369,25 @@ class DeviceTier:
             [(prov[side.elem_id], lo, hi) for _key, side, lo, hi in runs],
             cols,
             interpret=self.interpret,
+            bounded=self.bounded,
         )
         return self.adopt(merged.elem_id, arrays, merged.data.num_rows, replicated=True)
+
+    def warm(self, dtypes: Sequence[Any], max_rows: int) -> None:
+        """Compile what a bounded tier runs on arrays of ``dtypes`` up to
+        ``max_rows`` rows: the zero fill of each length and the placement
+        between each pair of lengths."""
+        import jax.numpy as jnp
+
+        sizes = [BOUNDED_MIN_ROWS]
+        while sizes[-1] < bounded_rows(max_rows):
+            sizes.append(2 * sizes[-1])
+        place = _placer()
+        for dt in dtypes:
+            arrays = [jnp.zeros(n, dt) for n in sizes]
+            for acc in arrays:
+                for src in arrays:
+                    place(acc, src, 0, 0, 1).block_until_ready()
 
     # -- demotion ------------------------------------------------------------
     def drop_element(self, elem_id: int) -> None:
@@ -354,19 +428,27 @@ def upload_residual(
     ledger: Dict[str, int],
     tracer: Tracer,
     site: str,
+    bounded: bool = False,
 ) -> Optional[Dict[str, Any]]:
     """Upload a fresh residual's columns: the one H2D transfer its bytes
     ever pay, since the arrays go to the cache insert and every later
     consumer, post-merge ones included, serves from device.  One
     ``device.h2d`` span (``site`` names the caller); None, uploading
-    nothing, when any column's dtype has no device analog."""
+    nothing, when any column's dtype has no device analog.  ``bounded``:
+    at :func:`bounded_rows` lengths, for a bounded tier."""
     if not all(DeviceTier.supported(fresh.column(c).dtype) for c in columns):
         return None
     import jax.numpy as jnp
 
     with tracer.span("device.h2d", site=site) as sp:
-        out = {c: jnp.asarray(fresh.column(c)) for c in columns}
-        h2d = sum(int(arr.nbytes) for arr in out.values())
+        if bounded:
+            out, h2d = {}, 0
+            for c in columns:
+                out[c], nbytes = _upload(fresh.column(c), True)
+                h2d += nbytes
+        else:
+            out = {c: jnp.asarray(fresh.column(c)) for c in columns}
+            h2d = sum(int(arr.nbytes) for arr in out.values())
         if tracer.enabled:
             sp.attrs["bytes"] = h2d
     _bump(ledger, "bytes_h2d", h2d)
@@ -383,6 +465,7 @@ def device_union(
     *,
     interpret: Optional[bool] = None,
     ledger: Optional[Dict[str, int]] = None,
+    bounded: bool = False,
 ) -> Dict[str, Any]:
     """Assemble the hit∪residual UNION on device.
 
@@ -395,7 +478,11 @@ def device_union(
     XLA slices, counted as ``gather_fallbacks``, and single-run groups are
     plain slices (a gather would be the identity).  Returns exact-length
     device columns, bitwise-equal to the numpy reference ``np.concatenate``
-    of the same slices followed by ``jnp.asarray``.
+    of the same slices followed by ``jnp.asarray``.  ``bounded`` (the
+    providers are a bounded tier's): the columns come at
+    :func:`bounded_rows` of the real rows instead, each run placed by one
+    program per pair of lengths, and the rows past the real ones are
+    padding.
     """
     import jax.numpy as jnp
 
@@ -403,6 +490,8 @@ def device_union(
 
     if not runs:
         return {}
+    if bounded:
+        return _bounded_union(runs, columns, ledger)
     # group consecutive runs by provider identity
     groups: List[Tuple[Mapping[str, Any], List[Tuple[int, int]]]] = []
     for arrays, lo, hi in runs:
@@ -438,37 +527,79 @@ def device_union(
     return out
 
 
+def _bounded_union(runs, columns, ledger) -> Dict[str, Any]:
+    import jax.numpy as jnp
+
+    first = runs[0][0]
+    runs = [(arrays, lo, hi) for arrays, lo, hi in runs if hi > lo]
+    total = sum(hi - lo for _arrays, lo, hi in runs)
+    size = bounded_rows(total)
+    place = _placer()
+    out: Dict[str, Any] = {}
+    for c in columns:
+        col = jnp.zeros(size, first[c].dtype)
+        off = 0
+        for arrays, lo, hi in runs:
+            col = place(col, arrays[c], lo, off, hi - lo)
+            off += hi - lo
+        out[c] = col
+        _bump(ledger, "device_union_bytes", int(np.dtype(col.dtype).itemsize) * total)
+    _bump(ledger, "device_unions")
+    _bump(ledger, "device_union_rows", total)
+    return out
+
+
+def _trimmed(arrays: Mapping[str, Any], rows: int) -> Dict[str, Any]:
+    return {c: (a if a.shape[0] == rows else a[:rows]) for c, a in arrays.items()}
+
+
 # ---------------------------------------------------------------------------
 # device-aware table wrappers
 # ---------------------------------------------------------------------------
 
-class DeviceTable(Table):
+class _DeviceColumns:
+    """``device_columns``: the device copies at the table's row count.  A
+    bounded tier's longer arrays are trimmed on first use, which only a jax
+    consumer makes (one slice per length)."""
+
+    __slots__ = ()
+
+    @property
+    def device_columns(self) -> Dict[str, Any]:
+        if self._exact is None:
+            self._exact = _trimmed(self._device, self.num_rows)
+        return self._exact
+
+
+class DeviceTable(_DeviceColumns, Table):
     """A host :class:`Table` carrying device-resident copies of (some of)
     its columns.  The host columns stay authoritative; ``device_columns``
     are advisory, bitwise-equal jax arrays a jax-runtime consumer uses to
     skip the H2D conversion.  Views (``select``/``slice``/…) return plain
     Tables — device association does not survive reshaping."""
 
-    __slots__ = ("device_columns",)
+    __slots__ = ("_device", "_exact")
 
     def __init__(self, host: Table, device_columns: Mapping[str, Any]):
         super().__init__({n: host.column(n) for n in host.column_names})
-        self.device_columns = dict(device_columns)
+        self._device = dict(device_columns)
+        self._exact = None
 
 
-class DeviceChunkedTable(ChunkedTable):
+class DeviceChunkedTable(_DeviceColumns, ChunkedTable):
     """A :class:`ChunkedTable` whose *combined* columns are also resident on
     device.  ``device_columns[c]`` equals ``jnp.asarray(self.column(c))``
     bitwise (chunk concatenation order)."""
 
-    __slots__ = ("device_columns",)
+    __slots__ = ("_device", "_exact")
 
     def __init__(self, chunks, device_columns: Mapping[str, Any]):
         super().__init__(chunks)
-        self.device_columns = dict(device_columns)
+        self._device = dict(device_columns)
+        self._exact = None
 
     def select(self, names):
         return DeviceChunkedTable(
             [c.select(names) for c in self.chunks],
-            {n: self.device_columns[n] for n in names if n in self.device_columns},
+            {n: self._device[n] for n in names if n in self._device},
         )

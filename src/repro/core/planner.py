@@ -105,6 +105,12 @@ class ScanExecutor:
         # lock fall back to a private one (single-executor use)
         self._lock = getattr(self.cache, "lock", None) or threading.Lock()
 
+    def _locked(self):
+        """The cache's lock; the wait for it is span ``store.lock_wait``."""
+        return self.tracer.acquire(
+            self._lock, "store.lock_wait", store="scan", tenant=self.tenant or ""
+        )
+
     def _claim_timeout(self) -> float:
         """Max seconds to wait on another executor's residual claim before
         replanning (and potentially taking the claim over) — configured on
@@ -194,7 +200,7 @@ class ScanExecutor:
                 plan_kwargs = {"tenant": self.tenant}
                 if use_device:
                     plan_kwargs["device_consumer"] = True
-                with self.tracer.span("scan.plan", table=table), self._lock:
+                with self._locked(), self.tracer.span("scan.plan", table=table):
                     q0 = getattr(self.cache, "plan_quarantines", 0)
                     plan = self.cache.plan(
                         scan, snapshot, meta.sort_key, **plan_kwargs
@@ -268,13 +274,15 @@ class ScanExecutor:
                 if dev_ok and fresh.num_rows:
                     from repro.core.device import upload_residual
 
-                    fresh_dev = upload_residual(fresh, proj, dev_ledger, self.tracer, "scan")
+                    fresh_dev = upload_residual(
+                        fresh, proj, dev_ledger, self.tracer, "scan", tier.bounded
+                    )
                     if fresh_dev is None:
                         dev_ok = False
                 insert_kwargs = {"tenant": self.tenant}
                 if fresh_dev is not None:
                     insert_kwargs["device_arrays"] = fresh_dev
-                with self.tracer.span("scan.insert", table=table), self._lock:
+                with self._locked(), self.tracer.span("scan.insert", table=table):
                     self.cache.insert(
                         scan, snapshot, meta.sort_key, plan.residual, fresh,
                         **insert_kwargs,
@@ -368,7 +376,8 @@ class ScanExecutor:
                 from repro.core.device import DeviceChunkedTable, device_union
 
                 arrays = device_union(
-                    dev_runs, proj, interpret=tier.interpret, ledger=dev_ledger
+                    dev_runs, proj, interpret=tier.interpret, ledger=dev_ledger,
+                    bounded=tier.bounded,
                 )
                 r = self.reports[-1]
                 r.gather_fast = dev_ledger.get("gather_fast", 0)

@@ -176,6 +176,15 @@ class Tracer:
             with self._lock:
                 self._roots.append(sp)
 
+    def acquire(self, lock, name: str, **attrs: Any):
+        """``lock`` as a context manager.  With the tracer on, the wait to
+        acquire it is recorded as a span ``name`` that ends when the lock
+        is held, so the spans opened under the lock time only their own
+        work; off, it is ``lock`` itself."""
+        if not self.enabled:
+            return lock
+        return _TimedAcquire(self, lock, name, attrs)
+
     def _stack(self) -> List[Span]:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
@@ -251,6 +260,21 @@ def chrome_trace(roots: Iterable[Span]) -> Dict[str, Any]:
             )
     events.sort(key=lambda e: e["ts"])
     return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+class _TimedAcquire:
+    __slots__ = ("tracer", "lock", "name", "attrs")
+
+    def __init__(self, tracer: Tracer, lock, name: str, attrs: Dict[str, Any]):
+        self.tracer, self.lock, self.name, self.attrs = tracer, lock, name, attrs
+
+    def __enter__(self) -> None:
+        t0 = time.perf_counter_ns()
+        self.lock.acquire()
+        self.tracer.add_span(self.name, t0, time.perf_counter_ns(), **self.attrs)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.lock.release()
 
 
 def _jsonable(v: Any) -> Any:

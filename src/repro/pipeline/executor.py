@@ -65,6 +65,7 @@ from repro.core.cache import (
     snapshots_usable_window,
 )
 from repro.core.columnar import ChunkedTable, Table, concat_tables
+from repro.core.device import ROW_BLOCK
 from repro.core.intervals import NEG_INF, POS_INF, Interval, IntervalSet
 from repro.core.planner import ScanExecutor
 from repro.lake.catalog import Catalog, Snapshot
@@ -75,7 +76,7 @@ from repro.pipeline.dsl import Project
 from repro.pipeline.filters import parse_filter
 from repro.pipeline.physical import PhysicalPlan, SystemScanStep, UserFnStep, compile_plan
 
-__all__ = ["Workspace", "RunResult", "run_project"]
+__all__ = ["Workspace", "RunResult", "run_project", "rowwise_lengths"]
 
 
 @dataclass
@@ -252,6 +253,13 @@ class Workspace:
         # demotes static contract violations to warnings at DAG time.
         self.enforce_scopes = enforce_scopes
         self.strict_contracts = strict_contracts
+
+    def _model_locked(self):
+        """The model store's lock; the wait for it is span
+        ``store.lock_wait``."""
+        return self.tracer.acquire(
+            self._model_lock, "store.lock_wait", store="model", tenant=self.tenant or ""
+        )
 
     # -- running -------------------------------------------------------------
     def run(
@@ -682,9 +690,9 @@ class Workspace:
                     cached_rows = 0
                     cache_bytes = 0
                     wait_event = None
-                    with self.tracer.span(
+                    with self._model_locked(), self.tracer.span(
                         "node.plan", model=step.model
-                    ), self._model_lock:
+                    ):
                         # cost is row-extent, not fragment bytes: serving ANY
                         # cached rows saves user-function compute, even inside
                         # a partially-covered fragment (unlike a physical
@@ -783,7 +791,9 @@ class Workspace:
                         else:
                             fresh_rows = total_in
                             out = _invoke(
-                                fn, step.runtime, kwargs, self.tracer, dev_ledger
+                                fn, step.runtime, kwargs, self.tracer, dev_ledger,
+                                rowwise=step.incremental == "rowwise"
+                                and len(kwargs) == 1,
                             )
                             fresh = self._windowed_output(step, kwargs, out)
                         res_sp.attrs["rows"] = fresh_rows
@@ -792,7 +802,8 @@ class Workspace:
                         from repro.core.device import upload_residual
 
                         fresh_dev = upload_residual(
-                            fresh, fresh.column_names, dev_ledger, self.tracer, "fresh"
+                            fresh, fresh.column_names, dev_ledger, self.tracer, "fresh",
+                            tier.bounded,
                         )
                         if fresh_dev is None:
                             dev_ok = False
@@ -801,9 +812,9 @@ class Workspace:
                         pins = pins_for(only_snap, mplan.residual)
                     else:
                         pins = multi_pins_for(snapshots, mplan.residual)
-                    with self.tracer.span(
+                    with self._model_locked(), self.tracer.span(
                         "node.insert", model=step.model
-                    ), self._model_lock:
+                    ):
                         # handing the fresh device arrays to the insert lets
                         # the store's merge replicate device→device — warm
                         # runs then upload only the residual, never the
@@ -903,6 +914,7 @@ class Workspace:
                     list(out_tbl.column_names),
                     interpret=tier.interpret,
                     ledger=dev_ledger,
+                    bounded=tier.bounded,
                 )
                 out_tbl = DeviceTable(out_tbl, arrays)
         stats = {
@@ -1195,13 +1207,39 @@ def _to_table(value: Any) -> Table:
     raise TypeError(f"model must return Table/ChunkedTable/dict, got {type(value)}")
 
 
+# a single-input rowwise jax stage runs on pieces of at most PIECE_ROWS
+# rows, each padded on the host to a power of two of at least ROW_BLOCK rows
+# and its outputs trimmed back after the copy: eager jax compiles every op
+# once per array length, and these are the only lengths such a stage meets,
+# whatever lengths its residuals take
+PIECE_ROWS = 1 << 20
+
+
+def _padded_length(rows: int) -> int:
+    return max(ROW_BLOCK, 1 << (rows - 1).bit_length())
+
+
+def rowwise_lengths(rows: int) -> List[int]:
+    """Every length a single-input rowwise jax stage is called at when its
+    input holds at most ``rows`` rows."""
+    top = _padded_length(min(rows, PIECE_ROWS))
+    return [ROW_BLOCK << k for k in range((top // ROW_BLOCK).bit_length())]
+
+
 def _invoke(
     fn: Callable,
     runtime: str,
     kwargs: Dict[str, Any],
     tracer: Tracer,
     ledger: Optional[Dict[str, int]] = None,
+    rowwise: bool = False,
 ) -> Table:
+    """Call a user function on its inputs.  ``rowwise`` marks a
+    single-input rowwise stage (each output row derives from its own input
+    row alone): a jax one then runs piece by piece, at the lengths
+    :func:`rowwise_lengths` lists.  A padded piece repeats its last
+    row, so its padding rows are in the stage's domain; their outputs are
+    copied back with the rest and dropped on the host."""
     if runtime == "numpy":
         prepared = {
             k: (v.combine() if isinstance(v, ChunkedTable) else v)
@@ -1210,62 +1248,90 @@ def _invoke(
         with tracer.span("node.call", runtime=runtime):
             out = fn(**prepared)
         return _to_table(out)
-    if runtime == "jax":
-        import jax
-        import jax.numpy as jnp
+    if runtime != "jax":
+        raise ValueError(f"unknown runtime {runtime!r}")
+    if rowwise:
+        ((arg, v),) = kwargs.items()
+        if v.num_rows:
+            host = v.combine() if isinstance(v, ChunkedTable) else v
+            outs = []
+            for lo in range(0, host.num_rows, PIECE_ROWS):
+                piece = host.slice(lo, min(lo + PIECE_ROWS, host.num_rows))
+                n = piece.num_rows
+                pad = _padded_length(n) - n
+                if pad:
+                    piece = Table({
+                        c: np.pad(piece.column(c), (0, pad), mode="edge")
+                        for c in piece.column_names
+                    })
+                out = _invoke_jax(fn, {arg: piece}, tracer, ledger)
+                outs.append({k: col[:n] for k, col in out.items()})
+            if len(outs) == 1:
+                return Table(outs[0])
+            return Table({k: np.concatenate([o[k] for o in outs]) for k in outs[0]})
+    return Table(_invoke_jax(fn, kwargs, tracer, ledger))
 
-        def _count(key: str, by: int) -> None:
-            if ledger is not None:
-                ledger[key] = ledger.get(key, 0) + by
 
-        prepared = {}
-        h2d = 0
-        with tracer.span("device.h2d", site="input") as sp:
-            for k, v in kwargs.items():
-                # device-resident inputs (DeviceTable / DeviceChunkedTable)
-                # hand their columns straight to the fn — zero host
-                # round-trips; any column without a device copy falls back
-                # to the H2D conversion
-                devcols = getattr(v, "device_columns", None) or {}
-                names = v.column_names
-                cols: Dict[str, Any] = {}
-                host = None
-                for name in names:
-                    arr = devcols.get(name)
-                    if arr is not None:
-                        _count("device_hits", 1)
-                    else:
-                        if host is None:
-                            host = v.combine() if isinstance(v, ChunkedTable) else v
-                        arr = jnp.asarray(host.column(name))
-                        nbytes = int(arr.nbytes)
-                        h2d += nbytes
-                        _count("bytes_h2d", nbytes)
-                    cols[name] = arr
-                prepared[k] = cols
-            if tracer.enabled:
-                sp.attrs["bytes"] = h2d
-        with tracer.span("node.call", runtime=runtime):
-            out = fn(**prepared)
-        if not isinstance(out, dict):
-            raise TypeError("jax models must return {column: jnp.ndarray}")
+def _invoke_jax(
+    fn: Callable,
+    kwargs: Dict[str, Any],
+    tracer: Tracer,
+    ledger: Optional[Dict[str, int]],
+) -> Dict[str, np.ndarray]:
+    import jax
+    import jax.numpy as jnp
+
+    def _count(key: str, by: int) -> None:
+        if ledger is not None:
+            ledger[key] = ledger.get(key, 0) + by
+
+    prepared = {}
+    h2d = 0
+    with tracer.span("device.h2d", site="input") as sp:
+        for k, v in kwargs.items():
+            # device-resident inputs (DeviceTable / DeviceChunkedTable)
+            # hand their columns straight to the fn — zero host
+            # round-trips; any column without a device copy falls back
+            # to the H2D conversion
+            devcols = getattr(v, "device_columns", None) or {}
+            names = v.column_names
+            cols: Dict[str, Any] = {}
+            host = None
+            for name in names:
+                arr = devcols.get(name)
+                if arr is not None:
+                    _count("device_hits", 1)
+                else:
+                    if host is None:
+                        host = v.combine() if isinstance(v, ChunkedTable) else v
+                    arr = jnp.asarray(host.column(name))
+                    nbytes = int(arr.nbytes)
+                    h2d += nbytes
+                    _count("bytes_h2d", nbytes)
+                cols[name] = arr
+            prepared[k] = cols
         if tracer.enabled:
-            # the host's wait for the device queue, apart from the copy
-            with tracer.span("device.wait"):
-                jax.block_until_ready(out)
-        host_out = {}
-        d2h = 0
-        with tracer.span("device.d2h") as sp:
-            for k, v in out.items():
-                arr = np.asarray(v)
-                nbytes = int(arr.nbytes)
-                d2h += nbytes
-                _count("bytes_d2h", nbytes)
-                host_out[k] = arr
-            if tracer.enabled:
-                sp.attrs["bytes"] = d2h
-        return Table(host_out)
-    raise ValueError(f"unknown runtime {runtime!r}")
+            sp.attrs["bytes"] = h2d
+    with tracer.span("node.call", runtime="jax"):
+        out = fn(**prepared)
+    if not isinstance(out, dict):
+        raise TypeError("jax models must return {column: jnp.ndarray}")
+    if tracer.enabled:
+        # the host's wait for the device queue, apart from the copy
+        with tracer.span("device.wait"):
+            jax.block_until_ready(out)
+    host_out = {}
+    d2h = 0
+    with tracer.span("device.d2h") as sp:
+        for k, v in out.items():
+            arr = np.asarray(v)
+            nbytes = int(arr.nbytes)
+            d2h += nbytes
+            _count("bytes_d2h", nbytes)
+            host_out[k] = arr
+        if tracer.enabled:
+            sp.attrs["bytes"] = d2h
+    return host_out
 
 
 def run_project(workspace: Workspace, project: Project, **kw) -> RunResult:

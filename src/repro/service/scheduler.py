@@ -85,6 +85,9 @@ class RunHandle:
     # the worker that dequeues this handle turns it into the queue-wait
     # histogram observation and trace span
     admit_ns: int = 0
+    # when the run retired and its result became the caller's
+    # (perf_counter_ns; 0 until then)
+    done_ns: int = 0
     _done: threading.Event = field(default_factory=threading.Event, repr=False)
 
     def wait(self, timeout: Optional[float] = None) -> "RunHandle":
@@ -147,6 +150,13 @@ class PipelineService:
     and ``spill_mode`` ("write_through" | "checkpoint") makes the spill
     tiers crash-warm instead of flush-on-shutdown-warm.  Startup recovers
     the catalog's publish journal (``journal_recovery`` holds the tally).
+
+    ``device`` puts one :class:`~repro.core.device.DeviceTier` (an
+    instance, or ``True`` for a bounded one) behind both shared stores, as
+    ``Workspace(device=...)`` does for one user: every tenant's jax stages
+    are served from, and replicate merges on, the same device copies.  Any
+    thread holding a store's lock may take the tier's lock, never the
+    reverse, so the two cannot deadlock.
     """
 
     def __init__(
@@ -172,6 +182,7 @@ class PipelineService:
         max_run_attempts: int = 1,
         run_retry: Optional[RetryPolicy] = None,
         spill_mode: Optional[str] = None,
+        device: Optional[Any] = None,
     ):
         # chaos wiring: a FaultPlan swaps in the fault-injecting store (its
         # default RetryPolicy absorbs transients below every consumer);
@@ -208,6 +219,13 @@ class PipelineService:
         # new service over the same root restores the tiers' manifests and
         # starts warm (clean shutdown demotes every resident element)
         self._spill_enabled = spill
+        if device is True:
+            from repro.core.device import DeviceTier
+
+            # the layouts a shared store's unions take follow the order its
+            # tenants plan in, so only a bounded tier compiles nothing new
+            device = DeviceTier(bounded=True)
+        self.device = device
         self.scan_cache = SharedScanCache(
             max_bytes=scan_cache_bytes,
             liveness_runs=liveness_runs,
@@ -218,6 +236,7 @@ class PipelineService:
             metrics_labels={"store": "scan"},
             tracer=self.tracer,
             spill_mode=spill_mode if spill else None,
+            device=device,
         )
         self.model_store = SharedStore(
             max_bytes=model_cache_bytes,
@@ -230,6 +249,7 @@ class PipelineService:
             metrics_labels={"store": "model"},
             tracer=self.tracer,
             spill_mode=spill_mode if spill else None,
+            device=device,
         )
         self.max_queued = max_queued
         self.max_commit_retries = max_commit_retries
@@ -402,6 +422,7 @@ class PipelineService:
                     except ValueError:  # pragma: no cover - defensive
                         pass
                     self._cond.notify_all()
+                handle.done_ns = time.perf_counter_ns()
                 handle._done.set()
 
     def _execute(self, handle: RunHandle) -> None:

@@ -172,6 +172,66 @@ def test_device_union_empty_runs_yield_empty_columns():
     assert np.asarray(got["x"]).shape == (0,)
 
 
+# ------------------------------------------------ bounded tier: closed shapes
+@pytest.mark.parametrize("dtype", ["<f8", "<i8", "|b1"])
+def test_bounded_union_equals_exact_union(dtype):
+    """A bounded tier's union holds the exact union's rows first, at a
+    power-of-two length; a DeviceTable over it trims to the real rows."""
+    from repro.core.device import DeviceTable, _upload, bounded_rows
+
+    rng = np.random.default_rng(len(dtype))
+    hosts = [
+        (rng.standard_normal(n) * 50).astype(dtype)
+        for n in (70_000, 3, 150_000)
+    ]
+    exact = [{"x": _pad_rows(jnp.asarray(h))} for h in hosts]
+    bounded = [{"x": _upload(h, True)[0]} for h in hosts]
+    layout = [(2, 5, 90_000), (0, 0, 3), (1, 1, 3), (0, 64_000, 70_000), (2, 90_000, 150_000)]
+    want = device_union([(exact[i], lo, hi) for i, lo, hi in layout], ["x"], interpret=True)
+    got = device_union(
+        [(bounded[i], lo, hi) for i, lo, hi in layout], ["x"], interpret=True, bounded=True
+    )
+    rows = int(want["x"].shape[0])
+    assert got["x"].shape == (bounded_rows(rows),)
+    np.testing.assert_array_equal(np.asarray(got["x"])[:rows], np.asarray(want["x"]))
+    table = DeviceTable(Table({"x": np.asarray(want["x"])}), got)
+    np.testing.assert_array_equal(np.asarray(table.device_columns["x"]), np.asarray(want["x"]))
+
+
+def test_bounded_tier_compiles_nothing_after_warm():
+    """After ``warm``, pins, uploads, unions and merge replicas of any
+    layout up to the warmed rows run without a compile."""
+    from jax import monitoring
+
+    from repro.core.device import upload_residual
+
+    tier = DeviceTier(interpret=True, bounded=True)
+    tier.warm([np.float32, np.int32], 400_000)
+    compiles = []
+
+    def listen(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        rng = np.random.default_rng(3)
+        for n in (5, 1_000, 65_537, 131_071, 199_999):
+            elem = _Elem(Table({"x": rng.standard_normal(n), "k": np.arange(n)}))
+            pinned = tier.pin_columns(elem, ["x", "k"])
+            fresh = upload_residual(
+                Table({"x": rng.standard_normal(n // 2 + 1), "k": np.arange(n // 2 + 1)}),
+                ["x", "k"], {}, tier.tracer, "test", bounded=True,
+            )
+            lo = int(rng.integers(0, n))
+            device_union(
+                [(pinned, lo, n), (fresh, 0, n // 2 + 1)], ["x", "k"], bounded=True
+            )["x"].block_until_ready()
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
 # ------------------------------------------------- fragment_gather regressions
 def test_fragment_gather_tail_not_padded_into_output():
     """The output is exactly the runs: a pin's tile-padded tail never leaks

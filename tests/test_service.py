@@ -640,6 +640,15 @@ def test_threaded_stress_no_torn_reads(tmp_path):
                     pipeline_project(hi=hi), rows=rows,
                 )
             assert_outputs_bitwise_equal(h.result, refs[hi])
+        # one more run alone, under new code: its insert overflows the
+        # budget while nothing else is in flight, so at least one unpinned
+        # element must go, whatever order the concurrent runs took above
+        alone = svc.run(readers[0].tenant_id, pipeline_project(hi=1199, gain=2.0))
+        assert_outputs_bitwise_equal(
+            alone,
+            cold_reference(tmp_path, "stress-cold-alone", pipeline_project(hi=1199, gain=2.0),
+                           rows=rows),
+        )
         assert svc.model_store.evictions > 0, "stress must actually evict"
         rep = svc.report()
         assert rep.model_store["cross_tenant_hits"] > 0
@@ -657,3 +666,243 @@ def test_service_bench_meets_3x_acceptance():
     assert result["min_bytes_ratio"] >= 3.0, result
     assert result["min_rows_ratio"] >= 3.0, result
     assert result["model_store"]["cross_tenant_hits"] > 0
+
+
+# ------------------------------------------- the service on the device tier
+LINES = "tpch.lineitem"
+LINE_SCHEMA = {
+    "l_shipdate": "<i8",
+    "l_quantity": "<f8",
+    "l_extendedprice": "<f8",
+    "l_discount": "<f8",
+    "l_tax": "<f8",
+    "l_returnflag": "|S1",
+    "l_linestatus": "|S1",
+}
+
+
+def lineitem_table(days=60, per_day=37, seed=3):
+    """A small lineitem-shaped table: whole days of lines in key order."""
+    rng = np.random.default_rng(seed)
+    n = days * per_day
+    ship = np.repeat(np.arange(1, days + 1, dtype=np.int64), per_day)
+    qty = rng.integers(1, 51, n).astype(np.float64)
+    return Table(
+        {
+            "l_shipdate": ship,
+            "l_quantity": qty,
+            "l_extendedprice": qty * rng.integers(90000, 200000, n) / 100.0,
+            "l_discount": rng.integers(0, 11, n) / 100.0,
+            "l_tax": rng.integers(0, 9, n) / 100.0,
+            "l_returnflag": np.where(ship < 30, np.where(rng.random(n) < 0.5, b"R", b"A"), b"N").astype("S1"),
+            "l_linestatus": np.where(ship < 35, b"F", b"O").astype("S1"),
+        }
+    )
+
+
+def write_lines(catalog):
+    catalog.create_table("tpch", "lineitem", LINE_SCHEMA, "l_shipdate")
+    catalog.append(LINES, lineitem_table())
+
+
+def q1_like(hi):
+    """Q1's shape: a jax rowwise price stage, a numpy grouping stage."""
+    where = f"l_shipdate >= 0 AND l_shipdate < {hi}"
+    p = Project("q1")
+
+    @model(project=p, incremental="rowwise")
+    @runtime("jax")
+    def prices(data=Model(LINES, columns=["l_extendedprice", "l_discount", "l_tax"], filter=where)):
+        import jax.numpy as jnp
+
+        one = jnp.float32(1)
+        disc_price = data["l_extendedprice"] * (one - data["l_discount"])
+        return {"disc_price": disc_price, "charge": disc_price * (one + data["l_tax"])}
+
+    @model(project=p)
+    @runtime("numpy")
+    def report(lines=Model(LINES, columns=["l_returnflag", "l_linestatus", "l_quantity"],
+                           filter=where),
+               priced=Model("prices")):
+        code = (lines.column("l_returnflag").view(np.uint8).astype(np.int64) * 256
+                + lines.column("l_linestatus").view(np.uint8))
+        groups, index = np.unique(code, return_inverse=True)
+        return {
+            "group": groups,
+            "sum_qty": np.bincount(index, weights=lines.column("l_quantity")),
+            "sum_charge": np.bincount(index, weights=priced.column("charge")),
+        }
+
+    return p
+
+
+def q6_like(lo, hi, cents):
+    """Q6's shape: a jax rowwise predicate-and-revenue stage, a numpy sum."""
+    where = f"l_shipdate >= {lo} AND l_shipdate < {hi}"
+    d_lo, d_hi = (cents - 1) / 100, (cents + 1) / 100
+    p = Project("q6")
+
+    @model(project=p, incremental="rowwise")
+    @runtime("jax")
+    def rev(data=Model(LINES, columns=["l_extendedprice", "l_discount", "l_quantity"], filter=where)):
+        import jax.numpy as jnp
+
+        disc = data["l_discount"]
+        keep = (disc >= jnp.float32(d_lo)) & (disc <= jnp.float32(d_hi)) & (
+            data["l_quantity"] < jnp.float32(24)
+        )
+        return {"keep": keep, "revenue": data["l_extendedprice"] * disc}
+
+    @model(project=p)
+    @runtime("numpy")
+    def total(r=Model("rev")):
+        return {"revenue": np.array([np.sum(r.column("revenue")[r.column("keep")], dtype=np.float64)])}
+
+    return p
+
+
+def jax_to_jax(hi):
+    """A jax stage read by a jax stage: the node's output is kept on device."""
+    where = f"l_shipdate >= 0 AND l_shipdate < {hi}"
+    p = Project("devfeat")
+
+    @model(project=p, incremental="rowwise")
+    @runtime("jax")
+    def feats(data=Model(LINES, columns=["l_extendedprice", "l_discount"], filter=where)):
+        return {"net": data["l_extendedprice"] * data["l_discount"]}
+
+    @model(project=p)
+    @runtime("jax")
+    def scaled(f=Model("feats")):
+        import jax.numpy as jnp
+
+        return {"net": f["net"] * jnp.float32(2), "l_shipdate": f["l_shipdate"]}
+
+    return p
+
+
+def cold_nocache(tmp_path, name, project):
+    from repro.core.baselines import NoCache
+
+    ws = Workspace(str(tmp_path / name), cache=NoCache())
+    write_lines(ws.catalog)
+    return ws.run(project)
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+def test_device_backed_service_matches_cold_runs(tmp_path, bounded):
+    """Four tenants, four workers, one DeviceTier behind both shared stores:
+    every output of every run equals a cold NoCache run bit for bit, and a
+    tenant asking what another computed on the device is served from the
+    device copies with nothing uploaded.  A bounded tier holds only
+    power-of-two lengths of at least ``BOUNDED_MIN_ROWS`` rows."""
+    from repro.core.device import BOUNDED_MIN_ROWS, DeviceTier
+
+    tier = DeviceTier(interpret=True, bounded=bounded)
+    projects = {
+        "q1.40": lambda: q1_like(40), "q1.55": lambda: q1_like(55), "q1.25": lambda: q1_like(25),
+        "q6.a": lambda: q6_like(10, 40, 6), "q6.b": lambda: q6_like(30, 61, 6),
+        "q6.c": lambda: q6_like(10, 40, 3),
+    }
+    order = ["q1.40", "q6.a", "q1.55", "q6.b", "q1.25", "q6.c", "q6.a", "q1.40"]
+    with PipelineService(str(tmp_path / "svc"), workers=4, rows_per_fragment=256,
+                         device=tier) as svc:
+        assert svc.scan_cache.device is tier and svc.model_store.device is tier
+        write_lines(svc.catalog)
+        tenants = ["t0", "t1", "t2", "t3"]
+        handles = [
+            (name, svc.submit(tenants[i % 4], projects[name]()))
+            for i, name in enumerate(order)
+        ]
+        svc.drain(120)
+        refs = {}
+        for name, h in handles:
+            assert h.state == DONE, h.error
+            if name not in refs:
+                refs[name] = cold_nocache(tmp_path, f"cold-{name}", projects[name]())
+            assert_outputs_bitwise_equal(h.result, refs[name])
+        assert svc.session("t0").workspace.device is tier
+
+        first = svc.run("t0", jax_to_jax(50))
+        second = svc.run("t1", jax_to_jax(50))
+        ref = cold_nocache(tmp_path, "cold-dev", jax_to_jax(50))
+        assert_outputs_bitwise_equal(first, ref)
+        assert_outputs_bitwise_equal(second, ref)
+        assert first.bytes_h2d > 0
+        assert second.device_hits > 0 and second.bytes_h2d == 0
+        assert svc.report().model_store["cross_tenant_hits"] > 0
+        stats = tier.stats()
+        assert stats["device_pins"] > 0 and stats["bytes_replicated"] > 0
+        if bounded:
+            lengths = {e.arr.shape[0] for e in tier._entries.values()}
+            assert all(n >= BOUNDED_MIN_ROWS and n & (n - 1) == 0 for n in lengths)
+
+
+@pytest.mark.parametrize("rows", [1023, 1024, 1025, 2047, 2048, 2049, (1 << 20) + 3])
+def test_rowwise_jax_stage_at_bounded_lengths(tmp_path, rows):
+    """A rowwise jax stage over ``rows`` input rows runs on pieces of at
+    most ``PIECE_ROWS`` rows, each padded to one of ``rowwise_lengths``:
+    its output equals one call on the whole input bit for bit, only those
+    lengths reach the stage, and the ledger counts the bytes that crossed,
+    padding included."""
+    import jax.numpy as jnp
+
+    from repro.obs import Tracer
+    from repro.pipeline.executor import PIECE_ROWS, _invoke, _padded_length, rowwise_lengths
+
+    rng = np.random.default_rng(rows)
+    table = Table({"k": np.arange(rows, dtype=np.int64), "x": rng.standard_normal(rows)})
+    seen = []
+
+    def fn(data):
+        seen.append(int(data["x"].shape[0]))
+        return {"y": data["x"] * jnp.float32(3) - jnp.float32(1), "ok": data["x"] > 0}
+
+    whole = _invoke(fn, "jax", {"data": table}, Tracer(enabled=False))
+    ledger = {}
+    seen.clear()
+    pieces = _invoke(fn, "jax", {"data": table}, Tracer(enabled=False), ledger, rowwise=True)
+    full, tail = divmod(rows, PIECE_ROWS)
+    assert seen == [PIECE_ROWS] * full + ([_padded_length(tail)] if tail else [])
+    assert set(seen) <= set(rowwise_lengths(rows))
+    for c in ("y", "ok"):
+        np.testing.assert_array_equal(pieces.column(c), whole.column(c))
+        assert len(pieces.column(c)) == rows
+    copied = sum(seen)
+    assert copied - rows < max(1024, tail)
+    assert ledger["bytes_d2h"] == copied * (4 + 1)
+    assert ledger["bytes_h2d"] == copied * (4 + 4)
+
+
+def test_lock_wait_span_only_with_the_tracer_on(tmp_path):
+    """Two tenants at once: each wait for a shared store's lock is a
+    ``store.lock_wait`` span beside (never inside) the plan and insert spans
+    it precedes; with the tracer off nothing is recorded."""
+    from repro.obs import Tracer
+
+    for enabled in (True, False):
+        tracer = Tracer(enabled=enabled)
+        with PipelineService(str(tmp_path / f"lw-{enabled}"), workers=2, rows_per_fragment=256,
+                             tracer=tracer) as svc:
+            write_lines(svc.catalog)
+            hs = [svc.submit(t, q1_like(50)) for t in ("a", "b")]
+            svc.drain(60)
+            assert all(h.state == DONE for h in hs)
+        spans = [sp for root in tracer.roots() for sp in root.walk()]
+        waits = [sp for sp in spans if sp.name == "store.lock_wait"]
+        if not enabled:
+            assert spans == []
+            continue
+        assert {w.attrs["store"] for w in waits} == {"scan", "model"}
+        assert {w.attrs["tenant"] for w in waits} == {"a", "b"}
+        critical = [sp for sp in spans if sp.name in ("scan.plan", "scan.insert", "node.plan", "node.insert")]
+        assert len(waits) == len(critical)
+        for sp in critical:
+            assert not any(c.name == "store.lock_wait" for c in sp.walk())
+        for parent in spans:
+            kids = parent.children
+            for i, c in enumerate(kids):
+                if c.name == "store.lock_wait":
+                    nxt = kids[i + 1]
+                    assert nxt.name in ("scan.plan", "scan.insert", "node.plan", "node.insert")
+                    assert c.t1_ns <= nxt.t0_ns

@@ -64,6 +64,7 @@ __all__ = [
     "DifferentialStore",
     "DifferentialCache",
     "FragmentPin",
+    "key_runs",
     "multi_pins_for",
     "next_elem_id",
     "pins_for",
@@ -72,6 +73,19 @@ __all__ = [
 ]
 
 _ID = itertools.count()
+
+
+def key_runs(keys: np.ndarray, window: IntervalSet) -> List[Tuple[Interval, int, int]]:
+    """The contiguous row runs of key-sorted ``keys`` inside ``window``:
+    ``(interval, lo, hi)`` half-open row bounds per non-empty interval, in
+    window order."""
+    runs: List[Tuple[Interval, int, int]] = []
+    for iv in window:
+        lo = int(np.searchsorted(keys, iv.lo, side="left"))
+        hi = int(np.searchsorted(keys, iv.hi, side="left"))
+        if hi > lo:
+            runs.append((iv, lo, hi))
+    return runs
 
 
 def next_elem_id() -> int:
@@ -148,25 +162,16 @@ class CacheElement:
         return frozenset(p.fragment_id for p in self.pins)
 
     def window_runs(self, window: IntervalSet) -> List[Tuple[Interval, int, int]]:
-        """The contiguous row runs of this element's payload inside
-        ``window``: ``(interval, lo, hi)`` half-open row bounds per
-        non-empty interval, in window order.  This is the single place the
-        interval→row mapping is computed — host slicing and device gather
-        assembly both derive from it, so they cannot disagree."""
+        """:func:`key_runs` of this element's payload.  Host slicing and
+        device gather assembly both derive from it, so they cannot
+        disagree."""
         if self.data is None:
             raise RuntimeError(
                 f"element {self.elem_id} is demoted; the planner promotes "
                 f"hits before handing them out — slicing a demoted element "
                 f"is a store-discipline bug"
             )
-        keys = self.data.column(self.sort_key)
-        runs: List[Tuple[Interval, int, int]] = []
-        for iv in window:
-            lo = int(np.searchsorted(keys, iv.lo, side="left"))
-            hi = int(np.searchsorted(keys, iv.hi, side="left"))
-            if hi > lo:
-                runs.append((iv, lo, hi))
-        return runs
+        return key_runs(self.data.column(self.sort_key), window)
 
     def slice_window(self, window: IntervalSet, columns: Sequence[str]) -> List[Table]:
         """Zero-copy chunks of this element's rows inside ``window``."""

@@ -60,6 +60,7 @@ import numpy as np
 from repro.core.cache import (
     DifferentialCache,
     DifferentialStore,
+    key_runs,
     multi_pins_for,
     pins_for,
     snapshots_usable_window,
@@ -681,11 +682,13 @@ class Workspace:
         try:
             with read_pin:
                 while True:
-                    hit_chunks: List[Table] = []
-                    # (window lo, provider arrays, row lo, row hi) — rebuilt
-                    # every replan round, the discarded round's plan is no
-                    # longer the store's truth
-                    dev_runs: List[Tuple] = []
+                    # the UNION's layout, one entry per key-sorted run:
+                    # (window lo, host table, device arrays, row lo, row hi).
+                    # Rebuilt every replan round — the discarded round's
+                    # plan is no longer the store's truth
+                    runs: List[Tuple] = []
+                    # a hit's schema, for a window that holds no rows
+                    empty: Optional[Table] = None
                     dev_ok = use_device
                     cached_rows = 0
                     cache_bytes = 0
@@ -731,12 +734,11 @@ class Workspace:
                         dev_h2d_plans += mplan.bytes_h2d
                         if wait_event is None:
                             for hit in mplan.hits:
-                                for view in hit.element.slice_window(
-                                    hit.window, hit.element.columns
-                                ):
-                                    hit_chunks.append(view)
-                                    cached_rows += view.num_rows
-                                    cache_bytes += view.nbytes
+                                hit_runs = hit.element.window_runs(hit.window)
+                                view = hit.element.data.select(hit.element.columns)
+                                if empty is None:
+                                    empty = view.slice(0, 0)
+                                arrays = None
                                 if dev_ok:
                                     # pin under the SAME lock the views are
                                     # taken under — a merge after release
@@ -746,15 +748,11 @@ class Workspace:
                                         hit.element.columns,
                                         dev_ledger,
                                     )
-                                    if arrays is None:
-                                        dev_ok = False
-                                        dev_runs = []
-                                    else:
-                                        dev_runs.extend(
-                                            (iv.lo, arrays, lo, hi)
-                                            for iv, lo, hi
-                                            in hit.element.window_runs(hit.window)
-                                        )
+                                    dev_ok = arrays is not None
+                                for iv, lo, hi in hit_runs:
+                                    runs.append((iv.lo, view, arrays, lo, hi))
+                                    cached_rows += hi - lo
+                                    cache_bytes += view.slice(lo, hi).nbytes
                     if wait_event is None:
                         break
                     # another run is computing an overlapping residual: wait
@@ -784,10 +782,10 @@ class Workspace:
                             step, plan, results, mplan.residual, snapshots, expl
                         )
                         total_in = sum(t.num_rows for t in kwargs.values())
-                        if total_in == 0 and hit_chunks:
+                        if total_in == 0 and empty is not None:
                             # nothing to compute; keep the output schema from
                             # a hit view
-                            fresh = hit_chunks[0].slice(0, 0)
+                            fresh = empty
                         else:
                             fresh_rows = total_in
                             out = _invoke(
@@ -797,6 +795,15 @@ class Workspace:
                             )
                             fresh = self._windowed_output(step, kwargs, out)
                         res_sp.attrs["rows"] = fresh_rows
+                    # fresh rows interleave with hit windows in key order:
+                    # one run per residual interval
+                    fresh_runs = key_runs(fresh.column(step.sort_key), mplan.residual)
+                    if sum(hi - lo for _, lo, hi in fresh_runs) != fresh.num_rows:
+                        raise ValueError(
+                            f"{step.model}: output keys outside the residual "
+                            f"window {mplan.residual} (an output row may only "
+                            f"derive from input rows at its own key)"
+                        )
                     fresh_dev = None
                     if dev_ok and fresh.num_rows:
                         from repro.core.device import upload_residual
@@ -830,16 +837,9 @@ class Workspace:
                             tenant=self.tenant,
                             device_arrays=fresh_dev,
                         )
-                    if dev_ok and fresh_dev is not None:
-                        # fresh rows interleave with hit windows in key
-                        # order: one run per residual interval, like the
-                        # host path's post-concat stable sort
-                        keys = np.asarray(fresh.column(step.sort_key))
-                        for iv in mplan.residual:
-                            lo = int(np.searchsorted(keys, iv.lo, side="left"))
-                            hi = int(np.searchsorted(keys, iv.hi, side="left"))
-                            if hi > lo:
-                                dev_runs.append((iv.lo, fresh_dev, lo, hi))
+                    runs.extend(
+                        (iv.lo, fresh, fresh_dev, lo, hi) for iv, lo, hi in fresh_runs
+                    )
         finally:
             if claim is not None:
                 self.model_store.release_residual(claim)
@@ -885,32 +885,29 @@ class Workspace:
                 "coalesced_wait_rounds", kind=step.incremental
             ).inc(waits)
 
-        chunks = hit_chunks + ([fresh] if fresh is not None else [])
-        # span the union only when there is one: the single-chunk serve is a
+        # hit and residual windows are disjoint intervals of the key and each
+        # run is key-sorted, so the runs in window order ARE the stable sort
+        # of their concatenation: equal keys never span runs
+        runs = _in_window_order(runs)
+        # span the union only when there is one: the single-run serve is a
         # zero-copy view and a span around it would just be tracer tax
         union_span = (
-            self.tracer.span("node.union", model=step.model, chunks=len(chunks))
-            if len(chunks) != 1 or (dev_ok and dev_runs)
+            self.tracer.span("node.union", model=step.model, runs=len(runs))
+            if len(runs) > 1 or (dev_ok and runs)
             else contextlib.nullcontext()
         )
         with union_span:
-            assembled = ChunkedTable(chunks)
-            if len(assembled.chunks) == 1:
-                # zero-copy fast path: a single chunk (one cache view, or one
-                # fresh residual) is already sorted by the key
-                out_tbl = assembled.chunks[0]
+            if runs:
+                out_tbl = _concat_runs(runs)
             else:
-                out_tbl = assembled.combine().sort_by(step.sort_key)
-            if dev_ok and dev_runs and out_tbl.num_rows:
-                # assemble the same UNION on device: hit/residual windows are
-                # disjoint and each run is internally key-sorted, so runs
-                # ordered by window lo ARE the host stable sort's output —
-                # bitwise (device_columns[c] == jnp.asarray(out_tbl.column(c)))
+                out_tbl = fresh if fresh is not None else empty
+            if dev_ok and runs:
+                # the same UNION on device, from the same runs — bitwise
+                # (device_columns[c] == jnp.asarray(out_tbl.column(c)))
                 from repro.core.device import DeviceTable, device_union
 
-                dev_runs.sort(key=lambda r: r[0])
                 arrays = device_union(
-                    [(prov, lo, hi) for _key, prov, lo, hi in dev_runs],
+                    [(dev, lo, hi) for _, _, dev, lo, hi in runs],
                     list(out_tbl.column_names),
                     interpret=tier.interpret,
                     ledger=dev_ledger,
@@ -1040,12 +1037,7 @@ class Workspace:
     def _rows_in(table: Table, keys: np.ndarray, window: IntervalSet) -> Optional[Table]:
         """``table``'s rows whose sort key lies inside ``window`` (table is
         sorted by the key); None when the window holds no rows."""
-        parts: List[Table] = []
-        for iv in window:
-            lo = int(np.searchsorted(keys, iv.lo, side="left"))
-            hi = int(np.searchsorted(keys, iv.hi, side="left"))
-            if hi > lo:
-                parts.append(table.slice(lo, hi))
+        parts = [table.slice(lo, hi) for _iv, lo, hi in key_runs(keys, window)]
         if not parts:
             return None
         return concat_tables(parts)
@@ -1213,6 +1205,32 @@ def _to_table(value: Any) -> Table:
 # once per array length, and these are the only lengths such a stage meets,
 # whatever lengths its residuals take
 PIECE_ROWS = 1 << 20
+
+
+def _in_window_order(runs: List[Tuple]) -> List[Tuple]:
+    """``(window lo, table, device arrays, row lo, row hi)`` runs sorted by
+    window lo, consecutive runs of adjacent rows of one table joined: the
+    residual's intervals with no hit between them are one slice of it."""
+    out: List[Tuple] = []
+    for run in sorted(runs, key=lambda r: r[0]):
+        if out and out[-1][1] is run[1] and out[-1][4] == run[3]:
+            out[-1] = out[-1][:4] + (run[4],)
+        else:
+            out.append(run)
+    return out
+
+
+def _concat_runs(runs: List[Tuple]) -> Table:
+    """The host UNION of ``(window lo, table, device arrays, row lo, row hi)``
+    runs given in window order: one concatenation per column.  A single run
+    stays a zero-copy view."""
+    if len(runs) == 1:
+        _, table, _, lo, hi = runs[0]
+        return table.slice(lo, hi)
+    return Table({
+        c: np.concatenate([t.column(c)[lo:hi] for _, t, _, lo, hi in runs])
+        for c in runs[0][1].column_names
+    })
 
 
 def _padded_length(rows: int) -> int:

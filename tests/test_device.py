@@ -394,6 +394,45 @@ def test_merge_never_sorts(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("bounded", [False, True], ids=["exact", "bounded"])
+def test_union_never_sorts(tmp_path, monkeypatch, bounded):
+    """A warm widen serves one hit and a residual on both sides of it: the
+    node concatenates their runs in window order, never sorting or combining
+    them, and the device union built from the same runs equals the host."""
+    import sys
+
+    from repro.core.device import DeviceTable
+
+    def refuse(cls, name):
+        orig = getattr(cls, name)
+
+        def spy(self, *args):
+            if sys._getframe(1).f_code.co_name == "_run_incremental":
+                raise AssertionError(f"the union called {cls.__name__}.{name}")
+            return orig(self, *args)
+
+        monkeypatch.setattr(cls, name, spy)
+
+    refuse(Table, "sort_by")
+    refuse(ChunkedTable, "sort_by")
+    refuse(ChunkedTable, "combine")
+    ws = Workspace(
+        str(tmp_path / "dev"), rows_per_fragment=128,
+        device=DeviceTier(interpret=True, bounded=bounded),
+    )
+    ws.catalog.create_table("ns", "raw", SCHEMA, "eventTime")
+    ws.catalog.append("ns.raw", events_table(0, 1024))
+    ws.run(jax_feature_project(_w(256, 512)))
+    res = ws.run(jax_feature_project(_w(0, 1024)))
+    stats = res.node_stats["cleaned"]
+    assert stats["cached_rows"] == 256 and stats["fresh_rows"] == 768
+    out = res.outputs["cleaned"]
+    assert isinstance(out, DeviceTable)
+    assert set(out.device_columns) == set(out.column_names)
+    for c, arr in out.device_columns.items():
+        np.testing.assert_array_equal(np.asarray(arr), out.column(c), err_msg=c)
+
+
 def test_spill_promotion_goes_straight_to_device(tmp_path):
     """A demoted element planned for a jax consumer promotes mmap → H2D
     once: resident on device, plan charged with the upload."""

@@ -24,7 +24,7 @@ from edit_matrix import (
     sweep,
 )
 from repro.core.cache import DifferentialCache, DifferentialStore
-from repro.core.columnar import Table
+from repro.core.columnar import ChunkedTable, Table
 from repro.core.intervals import IntervalSet
 from repro.pipeline import DagError, Model, Project, Workspace, build_dag, model, runtime
 from repro.pipeline.dsl import code_fingerprint
@@ -461,6 +461,175 @@ def test_degenerate_empty_window_runs_fn_on_empty_input(tmp_path):
     out = res.outputs["noop"]
     assert out.num_rows == 0
     assert set(out.column_names) == {"c1", "eventTime"}
+
+
+# ------------------------------------------------------- the node's host UNION
+def _where(pairs):
+    return " OR ".join(f"(eventTime >= {lo} AND eventTime < {hi})" for lo, hi in pairs)
+
+
+def _union_workspace(root, tracer=None):
+    """``ns.raw`` (unique keys), ``ns.rep`` (three rows per even key) and
+    ``ns.even`` (one row per even key), all keyed by ``eventTime``."""
+    ws = Workspace(root, rows_per_fragment=128, tracer=tracer)
+    ws.catalog.create_table("ns", "raw", SCHEMA, "eventTime")
+    ws.catalog.append("ns.raw", events_table(0, 1000))
+    rng = np.random.default_rng(5)
+    even = np.arange(0, 1000, 2, dtype=np.int64)
+    ws.catalog.create_table("ns", "rep", {"eventTime": "<i8", "v": "<f8"}, "eventTime")
+    ws.catalog.append(
+        "ns.rep", Table({"eventTime": np.repeat(even, 3), "v": rng.standard_normal(1500)})
+    )
+    ws.catalog.create_table("ns", "even", {"eventTime": "<i8", "ry": "<f8"}, "eventTime")
+    ws.catalog.append("ns.even", Table({"eventTime": even, "ry": rng.standard_normal(500)}))
+    return ws
+
+
+def _union_project(kind, pairs):
+    """One incremental node over the window ``pairs``: a rowwise map, a
+    rowwise function that drops rows, a keyed aggregation over repeated
+    keys, or a multi-input rowwise join."""
+    p = Project("union")
+    where = _where(pairs)
+    if kind in ("map", "drop"):
+
+        @model(project=p, incremental="rowwise")
+        @runtime("numpy")
+        def node(data=Model("ns.raw", columns=["c1", "c3"], filter=where)):
+            if kind == "drop":
+                data = data.filter(data.column("c3") % 3 != 0)
+            out = {n: data.column(n) for n in data.column_names}
+            out["score"] = 2.0 * np.asarray(data.column("c1"))
+            return out
+
+    elif kind == "keyed":
+
+        @model(project=p, incremental="keyed")
+        @runtime("numpy")
+        def node(data=Model("ns.rep", columns=["v"], filter=where)):
+            keys, starts = np.unique(np.asarray(data.column("eventTime")), return_index=True)
+            if keys.size == 0:
+                return {"eventTime": keys, "total": np.zeros(0)}
+            return {"eventTime": keys, "total": np.add.reduceat(data.column("v"), starts)}
+
+    else:
+
+        @model(project=p, incremental="rowwise")
+        @runtime("numpy")
+        def node(
+            left=Model("ns.raw", columns=["c1"], filter=where),
+            right=Model("ns.even", columns=["ry"], filter=where),
+        ):
+            common, li, ri = np.intersect1d(
+                left.column("eventTime"), right.column("eventTime"), return_indices=True
+            )
+            return {
+                "eventTime": common,
+                "c1": np.asarray(left.column("c1"))[li],
+                "ry": np.asarray(right.column("ry"))[ri],
+            }
+
+    return p
+
+
+def _assert_bitwise(a, b):
+    assert a.column_names == b.column_names
+    for c in a.column_names:
+        x, y = np.asarray(a.column(c)), np.asarray(b.column(c))
+        assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), c
+
+
+_APPEND = lambda c: c.append("ns.raw", events_table(1000, 1100, seed=9))
+
+
+@pytest.mark.parametrize(
+    "kind,primes,final,mutations,runs",
+    [
+        # the fresh table's two residual intervals around one hit
+        ("map", [[(300, 600)]], [(100, 900)], [], 3),
+        ("map", [[(0, 500)]], [(250, 750)], [], 2),
+        ("map", [[(0, 1100)]], [(0, 1100)], [_APPEND], 2),
+        # a split rerun: two views of one element, no residual
+        ("map", [[(0, 1000)]], [(0, 300), (600, 1000)], [], 2),
+        ("map", [[(0, 300), (600, 1000)]], [(37, 901)], [], 3),
+        ("map", [[(0, 300)], [(600, 1000)]], [(0, 1000)], [], 3),
+        ("drop", [[(300, 600)]], [(100, 900)], [], 3),
+        ("keyed", [[(300, 600)]], [(100, 900)], [], 3),
+        ("multi", [[(300, 600)]], [(100, 900)], [], 3),
+    ],
+    ids=["widen", "shift", "append", "split", "narrow_off_grid", "two_elements",
+         "drops_rows", "keyed", "multi_input"],
+)
+def test_union_concatenates_runs_in_window_order(
+    tmp_path, monkeypatch, kind, primes, final, mutations, runs
+):
+    """A warm serve of hits and residual is the concatenation of their runs
+    in window order: bit for bit the stable sort of the same chunks in any
+    order, and a cold run on the same snapshot."""
+    from repro.obs.trace import Tracer
+    from repro.pipeline import executor
+
+    tracer = Tracer()
+    ws = _union_workspace(str(tmp_path / "warm"), tracer)
+    for pairs in primes:
+        ws.run(_union_project(kind, pairs))
+    for m in mutations:
+        m(ws.catalog)
+    seen = []
+    concat = executor._concat_runs
+    monkeypatch.setattr(
+        executor, "_concat_runs", lambda r: seen.append((list(r), concat(r))) or seen[-1][1]
+    )
+    tracer.clear()
+    warm = ws.run(_union_project(kind, final))
+
+    assert warm.node_stats["node"]["cached_rows"] > 0
+    (got_runs, got), = seen
+    assert [sp.attrs["runs"] for sp in tracer.find("node.union")] == [runs] == [len(got_runs)]
+    assert [r[0] for r in got_runs] == sorted(r[0] for r in got_runs)
+    _assert_bitwise(got, warm.outputs["node"])
+    chunks = [t.slice(lo, hi) for _, t, _, lo, hi in got_runs]
+    _assert_bitwise(got, ChunkedTable(chunks[::-1]).combine().sort_by("eventTime"))
+
+    cold = _union_workspace(str(tmp_path / "cold"))
+    for m in mutations:
+        m(cold.catalog)
+    ref = cold.run(_union_project(kind, final))
+    _assert_bitwise(warm.outputs["node"], ref.outputs["node"])
+
+
+def test_cold_split_window_serves_one_slice(tmp_path, monkeypatch):
+    """A cold run over two intervals computes one residual whose runs hold
+    adjacent rows: they join into one zero-copy slice, and no union span
+    opens."""
+    from repro.obs.trace import Tracer
+    from repro.pipeline import executor
+
+    tracer = Tracer()
+    ws = _union_workspace(str(tmp_path / "lake"), tracer)
+    seen = []
+    concat = executor._concat_runs
+    monkeypatch.setattr(
+        executor, "_concat_runs", lambda r: seen.append(list(r)) or concat(r)
+    )
+    out = ws.run(_union_project("map", [(0, 300), (600, 1000)])).outputs["node"]
+    (((_, fresh, _, lo, hi),),) = seen
+    assert (lo, hi) == (0, fresh.num_rows) == (0, 700)
+    assert np.shares_memory(out.column("score"), fresh.column("score"))
+    keys = np.asarray(out.column("eventTime"))
+    assert np.array_equal(keys, np.r_[0:300, 600:1000])
+    assert tracer.find("node.union") == []
+
+
+def test_warm_rerun_of_a_window_with_no_rows(tmp_path):
+    """A window beyond the data caches an empty element; serving it again
+    gives the same empty, schema-complete output."""
+    ws = _union_workspace(str(tmp_path / "lake"))
+    cold = ws.run(_union_project("map", [(5000, 6000)]))
+    warm = ws.run(_union_project("map", [(5000, 6000)]))
+    assert warm.rows_to_user_fns == 0
+    assert warm.outputs["node"].num_rows == 0
+    _assert_bitwise(warm.outputs["node"], cold.outputs["node"])
 
 
 # -------------------------------------------------- acceptance: the ≥5× gate

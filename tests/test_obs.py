@@ -399,6 +399,22 @@ def test_merge_replicate_span_only_under_merge_with_a_device_tier(tmp_path, devi
         assert max(replicated) > 0
 
 
+def test_union_span_counts_runs_only_when_traced(tmp_path):
+    """``node.union`` carries the number of runs it concatenated.  A
+    single-run host-only serve (the cold pass, the rerun of a merged
+    window) opens no span, and a disabled tracer records none."""
+    tr = Tracer()
+    per_run = [
+        [sp.attrs["runs"] for sp in spans if sp.name == "node.union"]
+        for _res, spans in _edit_loop(str(tmp_path / "on"), tr, device=False)
+    ]
+    # cold, widen (hit + residual), rerun, shift, widen past the append
+    assert per_run == [[], [2], [], [2], [2]]
+    off = Tracer(enabled=False)
+    assert len(list(_edit_loop(str(tmp_path / "off"), off, device=False))) == 5
+    assert off.roots() == [] and off.find("node.union") == []
+
+
 def test_transfer_spans_add_up_to_the_run_ledger(tmp_path):
     """Every byte a run counts crossing the host link lies under one span
     name: ``device.h2d`` up (pins, fresh residuals, jax inputs) and

@@ -19,7 +19,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
-from repro.core.cache import CacheElement, CacheHit, CachePlan, DifferentialCache
+from repro.core.cache import CacheElement, CacheHit, CachePlan, CacheStore
 from repro.core.columnar import Table
 from repro.core.intervals import IntervalSet
 from repro.core.scan import Scan, scan_cost_bytes
@@ -32,7 +32,7 @@ if TYPE_CHECKING:  # annotation-only: a runtime import would close the
 __all__ = ["ScanCache", "NoCache"]
 
 
-class NoCache:
+class NoCache(CacheStore):
     """Every scan goes to object storage (the cold baseline)."""
 
     def plan(self, scan: Scan, snapshot: Snapshot, sort_key: str, tenant=None) -> CachePlan:
@@ -43,12 +43,13 @@ class NoCache:
         return None
 
 
-class ScanCache:
+class ScanCache(CacheStore):
     """Exact-match scan cache: key = (table, snapshot, physical columns,
     window).  No differential reuse — overlapping-but-different windows miss.
     """
 
     def __init__(self, max_bytes: Optional[int] = None):
+        super().__init__()
         self.max_bytes = max_bytes
         self._store: Dict[tuple, Tuple[IntervalSet, Table]] = {}
         self._order: List[tuple] = []

@@ -39,9 +39,10 @@ intermediate `@model` outputs (see ``repro.pipeline.executor``).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import TYPE_CHECKING, Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -59,6 +60,7 @@ if TYPE_CHECKING:  # annotation-only: a runtime import would close the
 
 __all__ = [
     "CacheElement",
+    "CacheStore",
     "CachePlan",
     "CacheHit",
     "DifferentialStore",
@@ -150,17 +152,6 @@ class CacheElement:
         """RAM-tier bytes: a demoted element holds no payload in memory."""
         return self.data.nbytes if self.data is not None else 0
 
-    @property
-    def payload_nbytes(self) -> int:
-        """Payload bytes wherever they live (RAM or spill)."""
-        if self.data is not None:
-            return self.data.nbytes
-        return self.spill.nbytes if self.spill is not None else 0
-
-    @property
-    def pin_ids(self) -> frozenset:
-        return frozenset(p.fragment_id for p in self.pins)
-
     def window_runs(self, window: IntervalSet) -> List[Tuple[Interval, int, int]]:
         """:func:`key_runs` of this element's payload.  Host slicing and
         device gather assembly both derive from it, so they cannot
@@ -201,14 +192,11 @@ class CachePlan:
     baseline_cost_bytes: int  # cost had there been no cache
     promoted_spill_bytes: int = 0  # payload bytes promoted spill -> RAM for hits
     bytes_h2d: int = 0  # host->device bytes for spill->device straight promotion
+    quarantined: int = 0  # spilled payloads this plan failed to verify and dropped
 
     @property
     def fully_cached(self) -> bool:
         return self.residual.empty
-
-    @property
-    def bytes_saved(self) -> int:
-        return self.baseline_cost_bytes - self.residual_cost_bytes
 
 
 def pins_for(snapshot: Snapshot, window: IntervalSet) -> Tuple[FragmentPin, ...]:
@@ -294,7 +282,53 @@ def snapshots_usable_window(
     return usable
 
 
-class DifferentialStore:
+class CacheStore:
+    """What the serve loop (:func:`repro.core.serve.serve`) asks of a store,
+    besides its own plan and insert: the lock both critical sections run
+    under, the device tier, residual claims and signature read pins.  A
+    plain store has no tier, claims nothing and pins nothing; the service's
+    :class:`~repro.service.store.SharedStore` overrides the claims, the pins
+    and the run clock."""
+
+    device = None  # the DeviceTier (repro.core.device), when one is attached
+    claim_timeout = 60.0  # seconds a waiter waits on a claim before replanning
+    metrics: Optional[Metrics] = None  # the registry the store counts into
+    tracer: Optional[Tracer] = None
+
+    def __init__(self) -> None:
+        # every executor sharing this store plans and inserts under this one
+        # lock.  Reentrant because service-layer subclasses compose base
+        # operations while already holding it.
+        self.lock = threading.RLock()
+
+    def claim_residual(
+        self,
+        signature: Hashable,
+        window: IntervalSet,
+        columns: Sequence[str] = (),
+        snapshot_id: Optional[str] = None,
+        kind: str = "window",
+    ) -> Tuple[Optional[object], Optional[threading.Event]]:
+        """``(claim, None)`` to compute the residual, ``(None, event)`` to
+        wait for another run's; a plain store coalesces nothing."""
+        return None, None
+
+    def release_residual(self, claim) -> None:
+        """Retire a claim :meth:`claim_residual` handed out."""
+
+    def reading(self, signature: Hashable):
+        """Pin ``signature``'s elements against reclamation for a node's
+        execution; a plain store reclaims only by its byte budget."""
+        return contextlib.nullcontext()
+
+    def elements(self, signature: Optional[Hashable] = None) -> List["CacheElement"]:
+        return []
+
+    def begin_run(self) -> None:
+        """Called once per pipeline run; a plain store keeps no run clock."""
+
+
+class DifferentialStore(CacheStore):
     """Greedy differential window store: a RAM tier with LRU byte-budget
     eviction over an optional **spill tier** of IPC files in object storage.
 
@@ -389,12 +423,7 @@ class DifferentialStore:
             device.adopt_obs(self.metrics, self.tracer)
         self._elements: Dict[Hashable, List[CacheElement]] = {}
         self._clock = 0
-        # The store's concurrency discipline lives HERE, not in its callers:
-        # every executor sharing this store must plan+slice (and insert)
-        # under this one lock, so two Workspaces injected with the same
-        # store serialize correctly.  Reentrant because service-layer
-        # subclasses compose base operations while already holding it.
-        self.lock = threading.RLock()
+        super().__init__()
         if spill is not None:
             for elem in spill.restore():
                 self._elements.setdefault(elem.signature, []).append(elem)
@@ -443,6 +472,7 @@ class DifferentialStore:
         self._clock += 1
         need = set(columns)
         baseline = cost_fn(window)
+        quarantined = 0
 
         # plan → promote, replanned from scratch whenever a chosen element's
         # spilled payload fails integrity verification: the element is
@@ -511,6 +541,7 @@ class DifferentialStore:
                     self.bytes_from_spill += e.data.nbytes
             if corrupt is not None:
                 self._quarantine_element(corrupt)
+                quarantined += 1
                 continue
             break
 
@@ -531,6 +562,7 @@ class DifferentialStore:
             baseline_cost_bytes=baseline,
             promoted_spill_bytes=promoted,
             bytes_h2d=bytes_h2d,
+            quarantined=quarantined,
         )
 
     def insert_window(
@@ -870,11 +902,6 @@ class DifferentialCache(DifferentialStore):
     invalidation against the scan's snapshot, and whose cost bound is the
     physical bytes a residual scan would move from object storage."""
 
-    def usable_window(self, elem: CacheElement, snapshot: Snapshot) -> IntervalSet:
-        """Differential invalidation (design choice 3) — see
-        :func:`snapshot_usable_window`."""
-        return snapshot_usable_window(elem, snapshot)
-
     def plan(
         self,
         scan: Scan,
@@ -917,6 +944,3 @@ class DifferentialCache(DifferentialStore):
             tenant=tenant,
             device_arrays=device_arrays,
         )
-
-    def invalidate_table(self, table: str) -> None:
-        self.invalidate(table)

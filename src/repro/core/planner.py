@@ -15,19 +15,18 @@ post-predicate included) is implemented here rather than in
 
 from __future__ import annotations
 
-import threading
-import time
 from collections import OrderedDict
-from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from dataclasses import dataclass
+from typing import TYPE_CHECKING, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.baselines import NoCache, ScanCache
-from repro.core.cache import DifferentialCache
+from repro.core.baselines import NoCache
+from repro.core.cache import CacheStore, DifferentialCache
 from repro.core.columnar import ChunkedTable, Table
 from repro.core.intervals import IntervalSet
-from repro.core.scan import Scan, read_window, scan_cost_bytes
+from repro.core.scan import Scan, read_window
+from repro.core.serve import DEVICE_FIELDS, ClaimKey, serve
 from repro.lake.s3sim import ObjectStore
 from repro.obs.explain import Explainer, RunExplanation
 from repro.obs.metrics import Metrics
@@ -35,7 +34,7 @@ from repro.obs.trace import Tracer, get_tracer
 
 if TYPE_CHECKING:  # annotation-only: a runtime import would close the
     # lake -> fragments -> core -> ... -> lake.catalog package cycle
-    from repro.lake.catalog import Catalog, Snapshot
+    from repro.lake.catalog import Catalog
 
 
 __all__ = ["ScanExecutor", "ScanReport", "ResultCachingExecutor", "Predicate"]
@@ -82,7 +81,7 @@ class ScanExecutor:
         self,
         store: ObjectStore,
         catalog: Catalog,
-        cache: Optional[Union[DifferentialCache, ScanCache, NoCache]] = None,
+        cache: Optional[CacheStore] = None,
         tenant: Optional[str] = None,
         tracer: Optional[Tracer] = None,
         metrics: Optional[Metrics] = None,
@@ -94,28 +93,10 @@ class ScanExecutor:
         self.tenant = tenant  # attribution when the cache is tenant-aware
         # obs wiring: share the cache's registry/tracer unless given one, so
         # spill-tier hit bytes and scan-level series land in ONE registry
-        self.tracer = tracer or getattr(self.cache, "tracer", None) or get_tracer()
-        self.metrics = metrics or getattr(self.cache, "metrics", None) or Metrics()
+        self.tracer = tracer or self.cache.tracer or get_tracer()
+        self.metrics = metrics or self.cache.metrics or Metrics()
         self.explainer = explainer if explainer is not None else Explainer()
         self.reports: List[ScanReport] = []
-        # the plan+slice / insert critical sections must serialize across
-        # EVERY executor sharing this cache object (repro.service gives each
-        # tenant session its own executor over one shared cache), so the
-        # lock is the cache's own when it has one; baseline caches without a
-        # lock fall back to a private one (single-executor use)
-        self._lock = getattr(self.cache, "lock", None) or threading.Lock()
-
-    def _locked(self):
-        """The cache's lock; the wait for it is span ``store.lock_wait``."""
-        return self.tracer.acquire(
-            self._lock, "store.lock_wait", store="scan", tenant=self.tenant or ""
-        )
-
-    def _claim_timeout(self) -> float:
-        """Max seconds to wait on another executor's residual claim before
-        replanning (and potentially taking the claim over) — configured on
-        the shared cache (``SharedStore(claim_timeout=...)``)."""
-        return float(getattr(self.cache, "claim_timeout", 60.0))
 
     # -- the system function -------------------------------------------------
     def scan(
@@ -130,268 +111,153 @@ class ScanExecutor:
         explain: Optional[RunExplanation] = None,
     ) -> ChunkedTable:
         with self.tracer.span("scan", table=table, tenant=self.tenant or ""):
-            return self._scan(
-                table, columns, window, snapshot_id, predicate,
-                sorted_output, device_consumer, explain,
+            meta = self.catalog.table(table)
+            snapshot = (
+                self.catalog.snapshot(table, snapshot_id)
+                if snapshot_id
+                else self.catalog.current_snapshot(table)
             )
+            window = window if window is not None else IntervalSet.everything()
+            scan = Scan(table, snapshot.snapshot_id, tuple(columns), window)
+            phys = scan.physical_columns(meta.sort_key)
+            proj = [c for c in phys if c in scan.columns]
 
-    def _scan(
-        self,
-        table: str,
-        columns: Sequence[str],
-        window: Optional[IntervalSet],
-        snapshot_id: Optional[str],
-        predicate: Optional[Predicate],
-        sorted_output: bool,
-        device_consumer: bool,
-        explain: Optional[RunExplanation],
-    ) -> ChunkedTable:
-        meta = self.catalog.table(table)
-        snapshot = (
-            self.catalog.snapshot(table, snapshot_id)
-            if snapshot_id
-            else self.catalog.current_snapshot(table)
-        )
-        window = window if window is not None else IntervalSet.everything()
-        scan = Scan(table, snapshot.snapshot_id, tuple(columns), window)
-        phys = scan.physical_columns(meta.sort_key)
-        proj = [c for c in phys if c in scan.columns]
+            # device serving path: only when the consumer declared itself a jax
+            # node AND the cache carries a device tier AND this scan's output is
+            # the raw hit∪residual UNION (a post-predicate or a host sort would
+            # reshape rows after assembly — those scans stay on the numpy path)
+            tier = (
+                self.cache.device
+                if device_consumer and predicate is None and not sorted_output
+                else None
+            )
+            plan_kwargs = {"device_consumer": True} if tier is not None else {}
 
-        # device serving path: only when the consumer declared itself a jax
-        # node AND the cache carries a device tier AND this scan's output is
-        # the raw hit∪residual UNION (a post-predicate or a host sort would
-        # reshape rows after assembly — those scans stay on the numpy path)
-        tier = getattr(self.cache, "device", None)
-        use_device = (
-            device_consumer
-            and tier is not None
-            and predicate is None
-            and not sorted_output
-        )
-        dev_ledger: Dict[str, int] = {}
-
-        # thread-local ledger: per-scan deltas stay exact when concurrent
-        # runs (repro.service workers) share this object store
-        ledger = self.store.thread_stats()
-        before = ledger.snapshot()
-        # plan AND slice the hits under one lock acquisition: between a plan
-        # and its slicing, a concurrent insert may merge or evict the very
-        # elements the plan's hits reference — the slices (zero-copy views
-        # over immutable buffers) must be taken while the plan is still the
-        # cache's current truth.  Shared caches also coalesce: claiming the
-        # residual in the SAME critical section as the plan means of N
-        # concurrent identical scans exactly one reads the residual from
-        # object storage and the rest subscribe, replan, and hit.
-        claimer = getattr(self.cache, "claim_residual", None)
-        claim = None
-        waits = 0
-        spill_bytes = 0  # accumulated across replan rounds (see executor)
-        quarantined = 0  # spill payloads failing integrity checks, ditto
-        elem_views: List[Tuple] = []  # pre-insert element state, for explain
-        try:
-            while True:
-                chunks: List[Table] = []
-                # device union layout, mirrored 1:1 with `chunks`: each entry
-                # is (provider arrays, lo, hi) in final chunk order
-                dev_runs: List[Tuple] = []
-                dev_ok = use_device
-                bytes_from_cache = 0
-                wait_event = None
-                plan_kwargs = {"tenant": self.tenant}
-                if use_device:
-                    plan_kwargs["device_consumer"] = True
-                with self._locked(), self.tracer.span("scan.plan", table=table):
-                    q0 = getattr(self.cache, "plan_quarantines", 0)
-                    plan = self.cache.plan(
-                        scan, snapshot, meta.sort_key, **plan_kwargs
-                    )
-                    quarantined += getattr(self.cache, "plan_quarantines", 0) - q0
-                    if (
-                        explain is not None
-                        and explain.enabled
-                        and not plan.residual.empty
-                    ):
-                        # immutable views of the pre-insert element state,
-                        # captured under the same lock the plan ran under;
-                        # the explainer only consults them on the recompute
-                        # path, so fully-served scans skip the copy
-                        elem_views = [
-                            (e.window, e.pins, e.columns, e.table)
-                            for e in getattr(self.cache, "elements", lambda s: ())(
-                                scan.table
-                            )
-                        ]
-                    spill_bytes += plan.promoted_spill_bytes
-                    if claimer is not None and not plan.residual.empty:
-                        claim, wait_event = claimer(
-                            scan.table, plan.residual, phys,
-                            snapshot_id=snapshot.snapshot_id,
-                            kind="scan",
-                        )
-                    if wait_event is None:
-                        for hit in plan.hits:
-                            views = hit.element.slice_window(hit.window, phys)
-                            for v in views:
-                                bytes_from_cache += v.nbytes
-                            chunks.extend(views)
-                            if dev_ok:
-                                # pin under the SAME lock the slices are
-                                # taken under: a concurrent merge drops the
-                                # element's pins the moment the plan stops
-                                # being the cache's current truth
-                                arrays = tier.pin_columns(
-                                    hit.element, proj, dev_ledger
-                                )
-                                if arrays is None:  # unsupported dtype/demoted
-                                    dev_ok = False
-                                    dev_runs = []
-                                else:
-                                    dev_runs.extend(
-                                        (arrays, lo, hi)
-                                        for _iv, lo, hi
-                                        in hit.element.window_runs(hit.window)
-                                    )
-                if wait_event is None:
-                    break
-                waits += 1
-                t_wait = time.perf_counter()
-                with self.tracer.span("scan.claim_wait", table=table):
-                    wait_event.wait(timeout=self._claim_timeout())
-                self.metrics.histogram("claim_wait_seconds", kind="scan").observe(
-                    time.perf_counter() - t_wait
+            def produce(residual: IntervalSet, _empty, _ledger) -> Tuple[Table, int]:
+                fresh = read_window(
+                    self.store, snapshot, residual, phys, meta.sort_key,
+                    schema=meta.schema,
                 )
-            hit_chunks = len(chunks)
+                return fresh, fresh.num_rows
 
-            residual_rows = 0
-            if not plan.residual.empty:
-                with self.tracer.span("scan.residual", table=table) as res_sp:
-                    fresh = read_window(
-                        self.store, snapshot, plan.residual, phys, meta.sort_key,
-                        schema=meta.schema,
-                    )
-                    res_sp.attrs["rows"] = fresh.num_rows
-                fresh_dev = None
-                if dev_ok and fresh.num_rows:
-                    from repro.core.device import upload_residual
-
-                    fresh_dev = upload_residual(
-                        fresh, proj, dev_ledger, self.tracer, "scan", tier.bounded
-                    )
-                    if fresh_dev is None:
-                        dev_ok = False
-                insert_kwargs = {"tenant": self.tenant}
-                if fresh_dev is not None:
-                    insert_kwargs["device_arrays"] = fresh_dev
-                with self._locked(), self.tracer.span("scan.insert", table=table):
-                    self.cache.insert(
-                        scan, snapshot, meta.sort_key, plan.residual, fresh,
-                        **insert_kwargs,
-                    )
-                if fresh.num_rows:
-                    residual_rows = fresh.num_rows
-                    chunks.append(fresh)
-                    if dev_ok:
-                        dev_runs.append((fresh_dev, 0, fresh.num_rows))
-        finally:
-            if claim is not None:
-                self.cache.release_residual(claim)
-
-        delta = ledger.delta(before)
-        self.reports.append(
-            ScanReport(
-                table=table,
-                snapshot_id=snapshot.snapshot_id,
-                columns=scan.columns,
-                window_pairs=window.to_pairs(),
-                bytes_from_store=delta.bytes_read,
-                bytes_from_cache=bytes_from_cache,
-                store_requests=delta.get_requests,
-                cache_chunks=hit_chunks,
-                fully_cached=plan.fully_cached,
-                residual_rows=residual_rows,
-                bytes_from_spill=spill_bytes,
-                bytes_mmap=delta.bytes_mmap,
-                coalesced_waits=waits,
-                bytes_h2d=dev_ledger.get("bytes_h2d", 0) + plan.bytes_h2d,
-                device_hits=dev_ledger.get("device_hits", 0),
-                gather_fast=dev_ledger.get("gather_fast", 0),
-                gather_fallbacks=dev_ledger.get("gather_fallbacks", 0),
-                device_union_bytes=dev_ledger.get("device_union_bytes", 0),
-            )
-        )
-
-        # the scan-level series the ScanReport fields reconcile against
-        m = self.metrics
-        m.counter("scan_requests", table=table).inc()
-        m.counter("bytes_from_store", table=table).inc(delta.bytes_read)
-        m.counter("store_requests", table=table).inc(delta.get_requests)
-        m.counter("bytes_mmap", table=table).inc(delta.bytes_mmap)
-        m.counter("cache_hit_bytes", tier="ram").inc(bytes_from_cache)
-        m.counter("residual_rows", kind="scan").inc(residual_rows)
-        if waits:
-            m.counter("coalesced_wait_rounds", kind="scan").inc(waits)
-
-        if explain is not None and explain.enabled:
-            def current_id() -> Optional[str]:
-                # lazy (only a genuine invalidation pays the pointer read)
-                # and memoized on the run's explanation
-                memo = explain.head_ids
-                if table not in memo:
-                    try:
-                        memo[table] = self.catalog.current_snapshot_id(table)
-                    except (KeyError, OSError):
-                        memo[table] = None
-                return memo[table]
-
-            hit_tier = "ram+spill" if spill_bytes else ("ram" if bytes_from_cache else "store")
-            self.explainer.classify_scan(
-                explain,
-                table=table,
-                window=window,
-                residual=plan.residual,
-                columns=tuple(phys),
-                elements=elem_views,
-                snapshot=snapshot,
-                current_id=current_id,
-                rows=residual_rows,
-                tier=hit_tier,
-                quarantined=quarantined,
-            )
-
-        with self.tracer.span("scan.union", table=table, chunks=len(chunks)):
-            out = ChunkedTable(chunks)
-            if predicate is not None:
-                out = ChunkedTable([c.filter(predicate(c)) for c in out.chunks])
-            # sort while the sort key is still physically present, THEN
-            # project it away unless requested — sorted_output must hold even
-            # when the key is not among the projections
-            if sorted_output and out.chunks:
-                out = ChunkedTable([out.combine().sort_by(meta.sort_key)])
-            out = out.select(proj)
-            if dev_ok and dev_runs:
-                # assemble the UNION on device too: run layout mirrors the
-                # host chunk order exactly, so device_columns[c] is
-                # bitwise-equal to jnp.asarray(out.column(c)) —
-                # property-checked in test_device
-                from repro.core.device import DeviceChunkedTable, device_union
-
-                arrays = device_union(
-                    dev_runs, proj, interpret=tier.interpret, ledger=dev_ledger,
-                    bounded=tier.bounded,
+            def insert(residual: IntervalSet, fresh: Table, device_arrays) -> None:
+                kwargs = {} if device_arrays is None else {"device_arrays": device_arrays}
+                self.cache.insert(
+                    scan, snapshot, meta.sort_key, residual, fresh,
+                    tenant=self.tenant, **kwargs,
                 )
-                r = self.reports[-1]
-                r.gather_fast = dev_ledger.get("gather_fast", 0)
-                r.gather_fallbacks = dev_ledger.get("gather_fallbacks", 0)
-                r.device_union_bytes = dev_ledger.get("device_union_bytes", 0)
-                out = DeviceChunkedTable(out.chunks, arrays)
-        return out
+
+            # thread-local ledger: per-scan deltas stay exact when concurrent
+            # runs (repro.service workers) share this object store
+            ledger = self.store.thread_stats()
+            before = ledger.snapshot()
+            served = serve(
+                self.cache,
+                self.tracer,
+                self.metrics,
+                span="scan",
+                attrs={"table": table},
+                lock_attrs={"store": "scan", "tenant": self.tenant or ""},
+                plan=lambda: self.cache.plan(
+                    scan, snapshot, meta.sort_key, tenant=self.tenant, **plan_kwargs
+                ),
+                produce=produce,
+                insert=insert,
+                claim=ClaimKey(scan.table, phys, snapshot.snapshot_id, "scan"),
+                sort_key=meta.sort_key,
+                tier=tier,
+                site="scan",
+                pin_columns=proj,
+                explain=explain is not None and explain.enabled,
+            )
+            delta = ledger.delta(before)
+            stats = served.stats
+            # the UNION's chunks: the hits' views in plan order, then the
+            # residual as one chunk
+            chunks = list(served.views)
+            fresh = served.fresh
+            if fresh is not None and fresh.num_rows:
+                chunks.append(fresh)
+
+            # the scan-level series the ScanReport fields reconcile against
+            m = self.metrics
+            m.counter("scan_requests", table=table).inc()
+            m.counter("bytes_from_store", table=table).inc(delta.bytes_read)
+            m.counter("store_requests", table=table).inc(delta.get_requests)
+            m.counter("bytes_mmap", table=table).inc(delta.bytes_mmap)
+
+            if explain is not None and explain.enabled:
+                hit_tier = (
+                    "ram+spill"
+                    if stats.bytes_from_spill
+                    else ("ram" if stats.cached_bytes else "store")
+                )
+                self.explainer.classify_scan(
+                    explain,
+                    table=table,
+                    window=window,
+                    residual=served.plan.residual,
+                    columns=tuple(phys),
+                    elements=served.elements,
+                    snapshot=snapshot,
+                    # lazy: only a genuine invalidation pays the pointer read
+                    current_id=lambda: explain.current_ids(self.catalog, [table])[table],
+                    rows=stats.fresh_rows,
+                    tier=hit_tier,
+                    quarantined=stats.quarantined,
+                )
+
+            with self.tracer.span("scan.union", table=table, chunks=len(chunks)):
+                out = ChunkedTable(chunks)
+                if predicate is not None:
+                    out = ChunkedTable([c.filter(predicate(c)) for c in out.chunks])
+                # sort while the sort key is still physically present, THEN
+                # project it away unless requested — sorted_output must hold even
+                # when the key is not among the projections
+                if sorted_output and out.chunks:
+                    out = ChunkedTable([out.combine().sort_by(meta.sort_key)])
+                out = out.select(proj)
+                if served.device and chunks:
+                    # assemble the UNION on device too: run layout mirrors the
+                    # host chunk order exactly, so device_columns[c] is
+                    # bitwise-equal to jnp.asarray(out.column(c)) —
+                    # property-checked in test_device
+                    from repro.core.device import DeviceChunkedTable, device_union
+
+                    hits = served.runs[: len(served.views)]
+                    dev_runs = [(dev, lo, hi) for _, _, dev, lo, hi in hits]
+                    if fresh is not None and fresh.num_rows:
+                        dev_runs.append((served.fresh_dev, 0, fresh.num_rows))
+                    arrays = device_union(
+                        dev_runs, proj, interpret=tier.interpret, ledger=stats.device,
+                        bounded=tier.bounded,
+                    )
+                    out = DeviceChunkedTable(out.chunks, arrays)
+
+            dev = stats.device
+            self.reports.append(
+                ScanReport(
+                    table=table,
+                    snapshot_id=snapshot.snapshot_id,
+                    columns=scan.columns,
+                    window_pairs=window.to_pairs(),
+                    bytes_from_store=delta.bytes_read,
+                    bytes_from_cache=stats.cached_bytes,
+                    store_requests=delta.get_requests,
+                    cache_chunks=len(served.views),
+                    fully_cached=served.plan.fully_cached,
+                    residual_rows=stats.fresh_rows,
+                    bytes_from_spill=stats.bytes_from_spill,
+                    bytes_mmap=delta.bytes_mmap,
+                    coalesced_waits=stats.coalesced_waits,
+                    **{f: dev.get(f, 0) for f in DEVICE_FIELDS},
+                )
+            )
+            return out
 
     # -- accounting ----------------------------------------------------------
     def total_bytes_processed(self) -> int:
         return sum(r.bytes_from_store for r in self.reports)
-
-    def reset_reports(self) -> None:
-        self.reports.clear()
 
 
 class ResultCachingExecutor:

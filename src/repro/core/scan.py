@@ -49,9 +49,6 @@ class Scan:
         readers must fetch the predicate column too)."""
         return tuple(sorted(set(self.columns) | {sort_key}))
 
-    def cache_key(self) -> tuple:
-        return (self.table, self.snapshot_id, self.columns, self.window.to_pairs())
-
 
 def fragments_overlapping(
     snapshot: Snapshot, window: IntervalSet

@@ -148,6 +148,18 @@ class RunExplanation:
             self.events.append(d)
             self.node_causes[d.node] = (d.cause, d.root or d.node)
 
+    def current_ids(self, catalog, tables) -> Dict[str, Optional[str]]:
+        """Each table's catalog-head snapshot id, memoized for the run.  A
+        pointer-only read (unaccounted), so the travel check never perturbs
+        the run's byte ledger."""
+        for t in tables:
+            if t not in self.head_ids:
+                try:
+                    self.head_ids[t] = catalog.current_snapshot_id(t)
+                except (KeyError, OSError):
+                    self.head_ids[t] = None
+        return {t: self.head_ids[t] for t in tables}
+
     def causes(self) -> Dict[str, str]:
         """node -> cause for every recorded decision."""
         return {d.node: d.cause for d in self.events}

@@ -50,14 +50,14 @@ is what makes the paper's multi-user §III-A workload work.
 from __future__ import annotations
 
 import contextlib
-import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.cache import (
+    CachePlan,
     DifferentialCache,
     DifferentialStore,
     key_runs,
@@ -69,6 +69,7 @@ from repro.core.columnar import ChunkedTable, Table, concat_tables
 from repro.core.device import ROW_BLOCK
 from repro.core.intervals import NEG_INF, POS_INF, Interval, IntervalSet
 from repro.core.planner import ScanExecutor
+from repro.core.serve import DEVICE_FIELDS, ClaimKey, serve
 from repro.lake.catalog import Catalog, Snapshot
 from repro.lake.s3sim import ObjectStore
 from repro.obs import Decision, Explainer, Metrics, RunExplanation, Tracer, get_tracer
@@ -173,17 +174,12 @@ class Workspace:
         # injected store's registry wins (the service wires every tenant
         # workspace to its shared one), so a single scrape covers the scan
         # cache, the model store, their spill/device tiers and the run loop
+        injected = [c for c in (model_store, cache) if c is not None]
         self.metrics = (
-            metrics
-            or getattr(model_store, "metrics", None)
-            or getattr(cache, "metrics", None)
-            or Metrics()
+            metrics or next((c.metrics for c in injected if c.metrics), None) or Metrics()
         )
         self.tracer = (
-            tracer
-            or getattr(model_store, "tracer", None)
-            or getattr(cache, "tracer", None)
-            or get_tracer()
+            tracer or next((c.tracer for c in injected if c.tracer), None) or get_tracer()
         )
         # the explainer is per-workspace by default: its cross-run signature
         # memory is keyed by node name, which is only meaningful within one
@@ -211,7 +207,7 @@ class Workspace:
         # insert happen under the STORE's lock (not a per-workspace one) so
         # a concurrent run's insert — possibly through a different Workspace
         # sharing the store — can't merge/evict an element between planning
-        # a hit and taking its views
+        # a hit and taking its views (see repro.core.serve)
         self.model_store = (
             model_store
             if model_store is not None
@@ -222,7 +218,6 @@ class Workspace:
                 tracer=self.tracer,
             )
         )
-        self._model_lock = self.model_store.lock
         # device tier (repro.core.device.DeviceTier): pass an instance, or
         # ``device=True`` for a default-budget tier.  One tier backs BOTH
         # caches so scan hits and model-output hits share the byte budget.
@@ -243,9 +238,7 @@ class Workspace:
             if self.model_store.device is None:
                 self.model_store.device = self.device
         else:
-            self.device = getattr(self.model_store, "device", None) or getattr(
-                self.scans.cache, "device", None
-            )
+            self.device = self.model_store.device or self.scans.cache.device
         self.tenant = tenant
         # plan-time scope enforcement (repro.analysis): reject any plan
         # whose scans request columns outside the consumer's verified or
@@ -254,13 +247,6 @@ class Workspace:
         # demotes static contract violations to warnings at DAG time.
         self.enforce_scopes = enforce_scopes
         self.strict_contracts = strict_contracts
-
-    def _model_locked(self):
-        """The model store's lock; the wait for it is span
-        ``store.lock_wait``."""
-        return self.tracer.acquire(
-            self._model_lock, "store.lock_wait", store="model", tenant=self.tenant or ""
-        )
 
     # -- running -------------------------------------------------------------
     def run(
@@ -303,9 +289,7 @@ class Workspace:
         # cache ticks too — its "signatures" are table names, so tables no
         # run has scanned for N runs are reclaimed the same way
         for shared in (self.model_store, self.scans.cache):
-            begin_run = getattr(shared, "begin_run", None)
-            if begin_run is not None:
-                begin_run()
+            shared.begin_run()
 
         results: Dict[str, Table] = {}
         node_stats: Dict[str, Dict[str, int]] = {}
@@ -341,7 +325,9 @@ class Workspace:
                         # cannot describe a join, so multi-leaf nodes
                         # republish in full
                         leaf_snap = (
-                            self._leaf_snapshot(step, leaf_snapshots, pins)
+                            self._leaf_snapshots_for(step, leaf_snapshots, pins)[
+                                step.leaf_table
+                            ]
                             if step.incremental in ("rowwise", "keyed")
                             and len(step.leaf_pairs) == 1
                             else None
@@ -352,6 +338,12 @@ class Workspace:
 
         delta = ledger.delta(before)
         scan_reports = self.scans.reports[reports_before:]
+        # the ledger fields both the node stats and the scan reports carry
+        shared_fields = {
+            f: sum(s.get(f, 0) for s in node_stats.values())
+            + sum(getattr(r, f) for r in scan_reports)
+            for f in ("bytes_from_spill", "coalesced_waits") + DEVICE_FIELDS
+        }
         result = RunResult(
             outputs=results,
             bytes_from_store=delta.bytes_read,
@@ -363,34 +355,13 @@ class Workspace:
                 s["model_cache_bytes"] for s in node_stats.values()
             ),
             node_stats=node_stats,
-            bytes_from_spill=sum(
-                s.get("bytes_from_spill", 0) for s in node_stats.values()
-            )
-            + sum(r.bytes_from_spill for r in scan_reports),
-            coalesced_waits=sum(
-                s.get("coalesced_waits", 0) for s in node_stats.values()
-            )
-            + sum(r.coalesced_waits for r in scan_reports),
-            bytes_h2d=sum(s.get("bytes_h2d", 0) for s in node_stats.values())
-            + sum(r.bytes_h2d for r in scan_reports),
             bytes_d2h=sum(s.get("bytes_d2h", 0) for s in node_stats.values()),
-            device_hits=sum(s.get("device_hits", 0) for s in node_stats.values())
-            + sum(r.device_hits for r in scan_reports),
             device_evictions=(
                 self.device.device_evictions - dev_evictions_before
                 if self.device is not None
                 else 0
             ),
-            gather_fast=sum(s.get("gather_fast", 0) for s in node_stats.values())
-            + sum(r.gather_fast for r in scan_reports),
-            gather_fallbacks=sum(
-                s.get("gather_fallbacks", 0) for s in node_stats.values()
-            )
-            + sum(r.gather_fallbacks for r in scan_reports),
-            device_union_bytes=sum(
-                s.get("device_union_bytes", 0) for s in node_stats.values()
-            )
-            + sum(r.device_union_bytes for r in scan_reports),
+            **shared_fields,
             bytes_mmap=delta.bytes_mmap,
             explanation=expl if expl.enabled else None,
         )
@@ -518,24 +489,6 @@ class Workspace:
         return out, stats
 
     # -- node execution: differential (incremental="rowwise"/"keyed") --------
-    def _leaf_snapshot(
-        self,
-        step: UserFnStep,
-        leaf_snapshots: Dict[Tuple[str, Optional[str]], Snapshot],
-        pins: Dict[str, str],
-    ) -> Snapshot:
-        snapshot_id = step.leaf_snapshot_id
-        if snapshot_id is None and pins:
-            snapshot_id = pins.get(step.leaf_table)
-        key = (step.leaf_table, snapshot_id)
-        if key not in leaf_snapshots:
-            if snapshot_id is not None:
-                snap = self.catalog.snapshot(step.leaf_table, snapshot_id)
-            else:
-                snap = self.catalog.current_snapshot(step.leaf_table)
-            leaf_snapshots[key] = snap
-        return leaf_snapshots[key]
-
     def _leaf_snapshots_for(
         self,
         step: UserFnStep,
@@ -558,23 +511,27 @@ class Workspace:
             out[table] = leaf_snapshots[key]
         return out
 
-    def _residual_input(
+    def _residual_inputs(
         self,
-        binding: Tuple[str, object],
         step: UserFnStep,
         plan: PhysicalPlan,
         results: Dict[str, Table],
         residual: IntervalSet,
         snapshots: Dict[str, Snapshot],
         expl: RunExplanation,
-    ) -> Table:
-        """One input of the node restricted to the residual window, sorted by
-        the sort key and always carrying the sort-key column.  For a
-        multi-input node this is the zip-aligned slice of that input: every
-        input is windowed by the SAME key, so slicing each one to the same
-        residual yields exactly the rows the function must align."""
-        (kind, ref) = binding
-        if kind == "scan":
+    ) -> Dict[str, Table]:
+        """Each input of the node restricted to the residual window, sorted
+        by the sort key and always carrying the sort-key column.  For a
+        multi-input node these are zip-aligned slices: every input is
+        windowed by the SAME key, so slicing each one to the same residual
+        yields exactly the rows the function must align."""
+        inputs: Dict[str, Table] = {}
+        for arg, (kind, ref) in step.bindings:
+            if kind != "scan":
+                upstream = results[ref]  # windowed upstream: sorted, has the key
+                rows = self._rows_in(upstream, upstream.column(step.sort_key), residual)
+                inputs[arg] = rows if rows is not None else upstream.slice(0, 0)
+                continue
             s = plan.scans[ref]
             # the sort key must ride along so the engine can window the
             # output; the scan cache itself is below this call
@@ -589,33 +546,16 @@ class Workspace:
                 snapshot_id=snapshots[s.table].snapshot_id,
             )
             chunked = self._exec_scan(s_with_key, window=residual, explain=expl)
-            if not chunked.chunks:
+            if chunked.chunks:
+                inputs[arg] = chunked.combine().sort_by(step.sort_key)
+            else:
                 # zero rows in the residual (e.g. a window widened beyond the
                 # data): keep the input schema-complete so the fn and the
                 # windowing below still see the declared columns
                 schema = self.catalog.table(s.table).schema
                 dt = lambda n: np.dtype(schema[n]) if n in schema else np.int64
-                return Table({n: np.empty(0, dtype=dt(n)) for n in cols})
-            return chunked.combine().sort_by(step.sort_key)
-        upstream = results[ref]  # windowed upstream: sorted, carries the key
-        rows = self._rows_in(upstream, upstream.column(step.sort_key), residual)
-        return rows if rows is not None else upstream.slice(0, 0)
-
-    def _residual_inputs(
-        self,
-        step: UserFnStep,
-        plan: PhysicalPlan,
-        results: Dict[str, Table],
-        residual: IntervalSet,
-        snapshots: Dict[str, Snapshot],
-        expl: RunExplanation,
-    ) -> Dict[str, Table]:
-        return {
-            arg: self._residual_input(
-                binding, step, plan, results, residual, snapshots, expl
-            )
-            for arg, binding in step.bindings
-        }
+                inputs[arg] = Table({n: np.empty(0, dtype=dt(n)) for n in cols})
+        return inputs
 
     def _run_incremental(
         self,
@@ -642,223 +582,95 @@ class Workspace:
                 "model_cache_bytes": 0,
             }
         usable_fn = lambda e: snapshots_usable_window(e, snapshots)
-        # one coalescing identity for the full snapshot vector: claims only
-        # match when EVERY leaf snapshot agrees (single-leaf nodes reduce to
-        # the plain snapshot id, matching the scan path's convention)
-        snapshot_token = ",".join(
-            f"{t}:{s.snapshot_id}" for t, s in sorted(snapshots.items())
-        )
+        # device serving: a jax-runtime node consumes the hit∪residual UNION
+        # as device arrays, skipping the H2D copy its _invoke would otherwise
+        # pay.  Bails to numpy whenever any hit column has no device analog.
+        tier = self.device if step.runtime == "jax" else None
+
+        def plan_window() -> CachePlan:
+            # cost is row-extent, not fragment bytes: serving ANY cached rows
+            # saves user-function compute, even inside a partially-covered
+            # fragment (unlike a physical scan, which must re-read the whole
+            # fragment's column chunks either way)
+            return self.model_store.plan_window(
+                signature=step.signature,
+                window=step.window,
+                columns=(),
+                cost_fn=lambda w: w.measure(),
+                usable_fn=usable_fn,
+                tenant=self.tenant,
+                device_consumer=tier is not None,
+            )
+
+        pins: Tuple = ()
+
+        def produce(
+            residual: IntervalSet, empty: Optional[Table], ledger: Dict[str, int]
+        ) -> Tuple[Table, int]:
+            nonlocal pins
+            # the insert's fragment pins, found before its lock is taken
+            pins = (
+                pins_for(*snapshots.values(), residual)
+                if len(snapshots) == 1
+                else multi_pins_for(snapshots, residual)
+            )
+            kwargs = self._residual_inputs(
+                step, plan, results, residual, snapshots, expl
+            )
+            total_in = sum(t.num_rows for t in kwargs.values())
+            if total_in == 0 and empty is not None:
+                # nothing to compute; keep the output schema from a hit view
+                return empty, 0
+            out = _invoke(
+                fn, step.runtime, kwargs, self.tracer, ledger,
+                rowwise=step.incremental == "rowwise" and len(kwargs) == 1,
+            )
+            return self._windowed_output(step, kwargs, out), total_in
+
+        def insert(residual: IntervalSet, fresh: Table, device_arrays) -> None:
+            self.model_store.insert_window(
+                signature=step.signature,
+                table=step.leaf_table,
+                sort_key=step.sort_key,
+                window=residual,
+                data=fresh,
+                pins=pins,
+                usable_fn=usable_fn,
+                tenant=self.tenant,
+                device_arrays=device_arrays,
+            )
+
         # hold a signature read-pin for the whole node execution: a shared
         # store must not liveness/LRU-reclaim the signature group an
         # in-flight run is working against (plain stores: no-op)
-        reading = getattr(self.model_store, "reading", None)
-        read_pin = reading(step.signature) if reading else contextlib.nullcontext()
-        # residual coalescing (shared stores only): claim the residual under
-        # the SAME lock acquisition as the plan, so of N concurrent runs
-        # planning an overlapping residual exactly one computes it and the
-        # rest subscribe to its claim, then replan against the inserted rows
-        claimer = getattr(self.model_store, "claim_residual", None)
-        claim = None
-        waits = 0
-        # accumulated across replan rounds: promotions a discarded plan
-        # triggered are still this run's doing (the elements stay resident
-        # for the final plan, which then reports 0 for them)
-        spill_bytes = 0
-        # spill payloads the plan quarantined (checksum/size mismatch) and
-        # replanned around — the explainer reports those residuals as
-        # corruption-driven, not cache-miss-driven
-        quarantined = 0
-        # device serving: a jax-runtime node consumes the hit∪residual UNION
-        # as device arrays (fragment_gather assembly), skipping the H2D copy
-        # its _invoke would otherwise pay.  Bails to numpy whenever any hit
-        # column has no device analog.
-        tier = self.device
-        use_device = tier is not None and step.runtime == "jax"
-        dev_ledger: Dict[str, int] = {}
-        dev_h2d_plans = 0  # spill→device straight-promotion bytes (from plans)
-        # immutable pre-plan element views (window, pins, columns, table),
-        # captured under the plan lock for the explainer's cause diagnosis
-        elem_views: List[Tuple] = []
-        try:
-            with read_pin:
-                while True:
-                    # the UNION's layout, one entry per key-sorted run:
-                    # (window lo, host table, device arrays, row lo, row hi).
-                    # Rebuilt every replan round — the discarded round's
-                    # plan is no longer the store's truth
-                    runs: List[Tuple] = []
-                    # a hit's schema, for a window that holds no rows
-                    empty: Optional[Table] = None
-                    dev_ok = use_device
-                    cached_rows = 0
-                    cache_bytes = 0
-                    wait_event = None
-                    with self._model_locked(), self.tracer.span(
-                        "node.plan", model=step.model
-                    ):
-                        # cost is row-extent, not fragment bytes: serving ANY
-                        # cached rows saves user-function compute, even inside
-                        # a partially-covered fragment (unlike a physical
-                        # scan, which must re-read the whole fragment's
-                        # column chunks either way)
-                        q0 = getattr(self.model_store, "plan_quarantines", 0)
-                        mplan = self.model_store.plan_window(
-                            signature=step.signature,
-                            window=step.window,
-                            columns=(),
-                            cost_fn=lambda w: w.measure(),
-                            usable_fn=usable_fn,
-                            tenant=self.tenant,
-                            device_consumer=use_device,
-                        )
-                        quarantined += (
-                            getattr(self.model_store, "plan_quarantines", 0) - q0
-                        )
-                        if expl.enabled and not mplan.residual.empty:
-                            # pre-insert element views, captured under the
-                            # plan's lock acquisition; the explainer only
-                            # consults them on the recompute path, so fully-
-                            # served runs skip the copy
-                            elem_views = [
-                                (e.window, e.pins, e.columns, e.table)
-                                for e in self.model_store.elements(step.signature)
-                            ]
-                        if claimer is not None and not mplan.residual.empty:
-                            claim, wait_event = claimer(
-                                step.signature,
-                                mplan.residual,
-                                snapshot_id=snapshot_token,
-                                kind=step.incremental,
-                            )
-                        spill_bytes += mplan.promoted_spill_bytes
-                        dev_h2d_plans += mplan.bytes_h2d
-                        if wait_event is None:
-                            for hit in mplan.hits:
-                                hit_runs = hit.element.window_runs(hit.window)
-                                view = hit.element.data.select(hit.element.columns)
-                                if empty is None:
-                                    empty = view.slice(0, 0)
-                                arrays = None
-                                if dev_ok:
-                                    # pin under the SAME lock the views are
-                                    # taken under — a merge after release
-                                    # drops this element's pins
-                                    arrays = tier.pin_columns(
-                                        hit.element,
-                                        hit.element.columns,
-                                        dev_ledger,
-                                    )
-                                    dev_ok = arrays is not None
-                                for iv, lo, hi in hit_runs:
-                                    runs.append((iv.lo, view, arrays, lo, hi))
-                                    cached_rows += hi - lo
-                                    cache_bytes += view.slice(lo, hi).nbytes
-                    if wait_event is None:
-                        break
-                    # another run is computing an overlapping residual: wait
-                    # (no lock held) and replan — its insert becomes our hit.
-                    # The timeout matches the store's claim lease, so a dead
-                    # owner's claim expires before the first waiter gives up;
-                    # owners release in a finally.
-                    waits += 1
-                    t_wait = time.perf_counter()
-                    with self.tracer.span("node.claim_wait", model=step.model):
-                        wait_event.wait(
-                            timeout=float(
-                                getattr(self.model_store, "claim_timeout", 60.0)
-                            )
-                        )
-                    self.metrics.histogram(
-                        "claim_wait_seconds", kind=step.incremental
-                    ).observe(time.perf_counter() - t_wait)
-
-                fresh: Optional[Table] = None
-                fresh_rows = 0
-                if not mplan.residual.empty:
-                    with self.tracer.span(
-                        "node.residual", model=step.model
-                    ) as res_sp:
-                        kwargs = self._residual_inputs(
-                            step, plan, results, mplan.residual, snapshots, expl
-                        )
-                        total_in = sum(t.num_rows for t in kwargs.values())
-                        if total_in == 0 and empty is not None:
-                            # nothing to compute; keep the output schema from
-                            # a hit view
-                            fresh = empty
-                        else:
-                            fresh_rows = total_in
-                            out = _invoke(
-                                fn, step.runtime, kwargs, self.tracer, dev_ledger,
-                                rowwise=step.incremental == "rowwise"
-                                and len(kwargs) == 1,
-                            )
-                            fresh = self._windowed_output(step, kwargs, out)
-                        res_sp.attrs["rows"] = fresh_rows
-                    # fresh rows interleave with hit windows in key order:
-                    # one run per residual interval
-                    fresh_runs = key_runs(fresh.column(step.sort_key), mplan.residual)
-                    if sum(hi - lo for _, lo, hi in fresh_runs) != fresh.num_rows:
-                        raise ValueError(
-                            f"{step.model}: output keys outside the residual "
-                            f"window {mplan.residual} (an output row may only "
-                            f"derive from input rows at its own key)"
-                        )
-                    fresh_dev = None
-                    if dev_ok and fresh.num_rows:
-                        from repro.core.device import upload_residual
-
-                        fresh_dev = upload_residual(
-                            fresh, fresh.column_names, dev_ledger, self.tracer, "fresh",
-                            tier.bounded,
-                        )
-                        if fresh_dev is None:
-                            dev_ok = False
-                    if len(snapshots) == 1:
-                        (only_snap,) = snapshots.values()
-                        pins = pins_for(only_snap, mplan.residual)
-                    else:
-                        pins = multi_pins_for(snapshots, mplan.residual)
-                    with self._model_locked(), self.tracer.span(
-                        "node.insert", model=step.model
-                    ):
-                        # handing the fresh device arrays to the insert lets
-                        # the store's merge replicate device→device — warm
-                        # runs then upload only the residual, never the
-                        # merged payload
-                        self.model_store.insert_window(
-                            signature=step.signature,
-                            table=step.leaf_table,
-                            sort_key=step.sort_key,
-                            window=mplan.residual,
-                            data=fresh,
-                            pins=pins,
-                            usable_fn=usable_fn,
-                            tenant=self.tenant,
-                            device_arrays=fresh_dev,
-                        )
-                    runs.extend(
-                        (iv.lo, fresh, fresh_dev, lo, hi) for iv, lo, hi in fresh_runs
-                    )
-        finally:
-            if claim is not None:
-                self.model_store.release_residual(claim)
+        with self.model_store.reading(step.signature):
+            served = serve(
+                self.model_store,
+                self.tracer,
+                self.metrics,
+                span="node",
+                attrs={"model": step.model},
+                lock_attrs={"store": "model", "tenant": self.tenant or ""},
+                plan=plan_window,
+                produce=produce,
+                insert=insert,
+                # one coalescing identity for the full snapshot vector: claims
+                # only match when EVERY leaf snapshot agrees (single-leaf nodes
+                # reduce to the plain snapshot id, matching the scan's)
+                claim=ClaimKey(
+                    step.signature,
+                    (),
+                    ",".join(f"{t}:{s.snapshot_id}" for t, s in sorted(snapshots.items())),
+                    step.incremental,
+                ),
+                sort_key=step.sort_key,
+                tier=tier,
+                site="fresh",
+                explain=expl.enabled,
+            )
+        stats = served.stats
 
         if expl.enabled:
-            def current_ids() -> Dict[str, Optional[str]]:
-                # the catalog head is a pointer-only read (unaccounted), so
-                # the travel check never perturbs the run's byte ledger;
-                # resolved lazily (only a genuine invalidation pays it) and
-                # memoized per run (every node asks about the same tables)
-                memo = expl.head_ids
-                for t in snapshots:
-                    if t not in memo:
-                        try:
-                            memo[t] = self.catalog.current_snapshot_id(t)
-                        except (KeyError, OSError):
-                            memo[t] = None
-                return {t: memo[t] for t in snapshots}
-
             with self.tracer.span("node.explain", model=step.model):
                 self.explainer.classify_node(
                     expl,
@@ -867,41 +679,37 @@ class Workspace:
                     sig_parts=step.sig_parts,
                     signature=step.signature,
                     window=step.window,
-                    residual=mplan.residual,
-                    elements=elem_views,
+                    residual=served.plan.residual,
+                    elements=served.elements,
                     snapshots=snapshots,
-                    current_ids=current_ids,
-                    rows=fresh_rows,
-                    tier="ram+spill" if spill_bytes else ("ram" if cached_rows else ""),
-                    quarantined=quarantined,
+                    # lazy: only a genuine invalidation pays the pointer read
+                    current_ids=lambda: expl.current_ids(self.catalog, snapshots),
+                    rows=stats.fresh_rows,
+                    tier=(
+                        "ram+spill"
+                        if stats.bytes_from_spill
+                        else ("ram" if stats.cached_rows else "")
+                    ),
+                    quarantined=stats.quarantined,
                 )
-        self.metrics.counter("residual_rows", kind=step.incremental).inc(
-            fresh_rows
-        )
-        if cache_bytes:
-            self.metrics.counter("cache_hit_bytes", tier="ram").inc(cache_bytes)
-        if waits:
-            self.metrics.counter(
-                "coalesced_wait_rounds", kind=step.incremental
-            ).inc(waits)
 
         # hit and residual windows are disjoint intervals of the key and each
         # run is key-sorted, so the runs in window order ARE the stable sort
         # of their concatenation: equal keys never span runs
-        runs = _in_window_order(runs)
+        runs = _in_window_order(served.runs)
         # span the union only when there is one: the single-run serve is a
         # zero-copy view and a span around it would just be tracer tax
         union_span = (
             self.tracer.span("node.union", model=step.model, runs=len(runs))
-            if len(runs) > 1 or (dev_ok and runs)
+            if len(runs) > 1 or (served.device and runs)
             else contextlib.nullcontext()
         )
         with union_span:
             if runs:
                 out_tbl = _concat_runs(runs)
             else:
-                out_tbl = fresh if fresh is not None else empty
-            if dev_ok and runs:
+                out_tbl = served.fresh if served.fresh is not None else served.empty
+            if served.device and runs:
                 # the same UNION on device, from the same runs — bitwise
                 # (device_columns[c] == jnp.asarray(out_tbl.column(c)))
                 from repro.core.device import DeviceTable, device_union
@@ -910,21 +718,19 @@ class Workspace:
                     [(dev, lo, hi) for _, _, dev, lo, hi in runs],
                     list(out_tbl.column_names),
                     interpret=tier.interpret,
-                    ledger=dev_ledger,
+                    ledger=stats.device,
                     bounded=tier.bounded,
                 )
                 out_tbl = DeviceTable(out_tbl, arrays)
-        stats = {
-            "fresh_rows": fresh_rows,
-            "cached_rows": cached_rows,
-            "model_cache_bytes": cache_bytes,
-            "bytes_from_spill": spill_bytes,
-            "coalesced_waits": waits,
+        node_stats = {
+            "fresh_rows": stats.fresh_rows,
+            "cached_rows": stats.cached_rows,
+            "model_cache_bytes": stats.cached_bytes,
+            "bytes_from_spill": stats.bytes_from_spill,
+            "coalesced_waits": stats.coalesced_waits,
         }
-        stats.update(dev_ledger)
-        if dev_h2d_plans:
-            stats["bytes_h2d"] = stats.get("bytes_h2d", 0) + dev_h2d_plans
-        return out_tbl, stats
+        node_stats.update(stats.device)
+        return out_tbl, node_stats
 
     def _windowed_output(
         self, step: UserFnStep, inputs: Dict[str, Table], out: Table

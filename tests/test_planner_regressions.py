@@ -119,6 +119,19 @@ def test_cache_chunks_counts_only_hit_views(env):
     assert part.cache_chunks == 1
     assert part.residual_rows == 100
 
+    # two hits: the greedy plan takes the larger element first, so the
+    # chunks are the hits in plan order (not window order), then the
+    # residual as one chunk
+    ex.scan("ns.raw", ["c1"], IntervalSet.of((512, 896)))
+    out = ex.scan("ns.raw", ["c1", "eventTime"], IntervalSet.of((0, 1000)))
+    two = ex.reports[-1]
+    assert two.cache_chunks == 2
+    assert two.residual_rows == 1000 - 300 - 384
+    keys = [c.column("eventTime").tolist() for c in out.chunks]
+    assert keys[0] == list(range(512, 896))
+    assert keys[1] == list(range(0, 300))
+    assert keys[2] == list(range(300, 512)) + list(range(896, 1000))
+
 
 def test_concurrent_scans_and_appends_stay_correct(env):
     """Threads doing overlapping scans while others append: every scan's
